@@ -14,8 +14,10 @@ perf snapshots at the repo root, so successive PRs accumulate a
 trajectory.  ``--only <name>`` runs a single bench — the
 full sweep is far too slow when iterating on one table.
 
-The forest-roofline bench needs 512 placeholder devices, so it runs as a
-subprocess (this process keeps the single real CPU device).
+The forest-roofline bench is a dry run on 512 placeholder host
+devices, so it runs as a CPU-only subprocess (``JAX_PLATFORMS=cpu``):
+on a chip machine this process holds the chip, and a child that
+reached for it would fail or hang.
 """
 from __future__ import annotations
 
@@ -25,12 +27,15 @@ import subprocess
 import sys
 import time
 
+from repro.compile_cache import setup_compile_cache
+
 from .common import SCALE
 
 
 def _run_roofline() -> None:
     env = dict(os.environ)
     env["PYTHONPATH"] = env.get("PYTHONPATH", "src")
+    env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run(
         [sys.executable, "-m", "benchmarks.roofline_forest"],
         env=env, cwd=os.path.join(os.path.dirname(__file__), ".."))
@@ -84,6 +89,7 @@ def main() -> None:
         ap.error(f"unknown bench {args.only!r}; choose from "
                  f"{sorted(benches)}")
 
+    setup_compile_cache()
     t0 = time.time()
     print(f"[bench] scale={SCALE}")
     selected = {args.only: benches[args.only]} if args.only else benches

@@ -129,8 +129,8 @@ def check_cascade(ds, qf, X, engine="bitvector"):
 def check_cascade_fused(ds, qf, X):
     """Fused-execution smoke: fused must be bit-exact with the staged
     loop (scores AND per-stage exit counts) on the quantized forest —
-    every jax engine, plus the single-kernel Pallas tier (interpret
-    mode, a few rows: interpret is slow)."""
+    every jax engine, plus the single-kernel Pallas tier (a few rows:
+    the CPU runs Pallas in its slow interpreter)."""
     from repro.cascade import (CascadePredictor, CascadeSpec,
                                FusedCascadePredictor, MarginGate)
     spec = CascadeSpec(stages=(max(qf.n_trees // 4, 1), qf.n_trees),
@@ -148,8 +148,7 @@ def check_cascade_fused(ds, qf, X):
         _check(f"fused-{engine}", err, 1e-12)
     staged = CascadePredictor(qf, spec, engine="bitvector")
     fused = FusedCascadePredictor(qf, fspec, engine="bitvector",
-                                  backend="pallas",
-                                  engine_kw={"interpret": True})
+                                  backend="pallas")
     err = float(np.abs(fused.predict(X[:8]) - staged.predict(X[:8])).max())
     if not np.array_equal(fused.last_exit_counts, staged.last_exit_counts):
         err = np.inf
@@ -173,12 +172,11 @@ def check_optimize(forest, qf, X):
         q2 = core.compile_forest(qf, engine=engine, opt=2)
         _check(f"O2-quant-{engine}",          # bit-exact: integer sums
                float(np.abs(q2.predict(X) - q0.predict(X)).max()), 1e-12)
-    # Pallas backends in interpret mode, a few rows (interpret is slow)
+    # Pallas backends, a few rows (the CPU interpreter is slow)
     for spec in registry.specs("pallas"):
-        p0 = core.compile_forest(qf, engine=spec.name, backend="pallas",
-                                 interpret=True)
+        p0 = core.compile_forest(qf, engine=spec.name, backend="pallas")
         p2 = core.compile_forest(qf, engine=spec.name, backend="pallas",
-                                 interpret=True, opt=2)
+                                 opt=2)
         _check(f"O2-{spec.tune_name}",
                float(np.abs(p2.predict(X[:8]) - p0.predict(X[:8])).max()),
                1e-12)
@@ -203,8 +201,7 @@ def check_int(ds, forest, X):
         err = 0.0 if np.array_equal(pred.predict(X), oracle) else np.inf
         _check(f"int-{engine}", err, 1e-12)
     for spec in registry.specs("pallas"):
-        pred = core.compile_forest(qi, engine=spec.name, backend="pallas",
-                                   interpret=True)
+        pred = core.compile_forest(qi, engine=spec.name, backend="pallas")
         err = 0.0 if np.array_equal(pred.predict(X[:8]), oracle[:8]) \
             else np.inf
         _check(f"int-{spec.tune_name}", err, 1e-12)

@@ -86,11 +86,14 @@ def _f32_up(x64: np.ndarray) -> np.ndarray:
 
 def _argmax_onehot(s: jnp.ndarray) -> jnp.ndarray:
     """(n, C) → boolean one-hot of the *first* row maximum — matches
-    ``np.argmax`` tie-breaking without ``argmax``/``one_hot`` ops (both
-    awkward inside Mosaic kernel bodies: plain compare/cumsum lower
-    everywhere)."""
+    ``np.argmax`` tie-breaking without ``argmax``/``one_hot``/``cumsum``
+    ops, none of which lower inside a Mosaic kernel body: the first
+    maximum is the smallest column index among the maxima."""
+    C = s.shape[1]
+    col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     eq = s == jnp.max(s, axis=1, keepdims=True)
-    return eq & (jnp.cumsum(eq.astype(jnp.int32), axis=1) == 1)
+    first = jnp.min(jnp.where(eq, col, C), axis=1, keepdims=True)
+    return col == first
 
 
 @dataclass

@@ -154,8 +154,11 @@ def eval_gemm(g: CompiledGEMM, X: jnp.ndarray) -> jnp.ndarray:
     R = jnp.einsum("btn,tnl->btl", S, g.A)                       # MXU
     hit = R == g.Bvec[None]                                      # (B, T, L)
     if g.leaf_val.dtype == jnp.float32:
+        # HIGHEST: a TPU's default precision rounds the f32 leaf values
+        # to bf16 (CPU f32 is exact either way, so tests can't see it)
         score = jnp.einsum("btl,tlc->bc", hit.astype(jnp.float32),
-                           g.leaf_val)                           # MXU
+                           g.leaf_val,
+                           precision=jax.lax.Precision.HIGHEST)  # MXU
     else:
         # integer leaves: exactly one leaf per (row, tree) matches its
         # left-edge count, so argmax recovers the exit leaf; the gather-

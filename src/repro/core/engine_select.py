@@ -37,7 +37,8 @@ top-k predicted candidates instead of the full product.
 
 Pallas engines run in interpret mode on CPU (orders of magnitude slower
 than compiled XLA), so they only enter the candidate set on a real TPU
-backend — or explicitly via ``engines=``/``include_pallas=True``.
+backend, where they compile — or explicitly via
+``engines=``/``include_pallas=True``.
 """
 from __future__ import annotations
 
@@ -139,8 +140,7 @@ def _make_factory(name: str) -> Callable[[Forest], object]:
     spec = registry.by_tune_name(name)
 
     def factory(forest: Forest):
-        kw = {"interpret": _interpret()} if spec.backend == "pallas" else {}
-        return registry.build(forest, spec.name, spec.backend, **kw)
+        return registry.build(forest, spec.name, spec.backend)
 
     return factory
 
@@ -174,10 +174,6 @@ def pallas_engines() -> tuple:
 def _on_tpu() -> bool:
     import jax
     return jax.default_backend() == "tpu"
-
-
-def _interpret() -> bool:
-    return not _on_tpu()
 
 
 def default_engines(include_pallas: Optional[bool] = None) -> tuple:
@@ -536,8 +532,6 @@ def _candidate_factories(forest: Forest, engines: tuple,
                 f"engine {name!r} cannot run tree-sharded "
                 f"(n_devices={n_devices}); restrict engines= to "
                 f"{[s.tune_name for s in registry.specs() if s.shardable]}")
-        if spec.backend == "pallas":
-            ekw.setdefault("interpret", _interpret())
 
         def factory():
             from .pipeline import CompilePlan, compile_plan

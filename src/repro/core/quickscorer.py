@@ -311,26 +311,28 @@ def compile_qs_bitmm(forest: Forest,
 
 
 def bitmm_exit_leaf(words: jnp.ndarray, *, bits: int, npack: int,
-                    n_leaves: int) -> jnp.ndarray:
-    """Packed clear-count words (..., G) f32 → exit leaf (...,) int32.
+                    n_leaves: int, axis: int = -1) -> jnp.ndarray:
+    """Packed clear-count words f32 (groups G on ``axis``) → exit leaf
+    int32, ``axis`` reduced to size 1.
 
     Lowest-zero-field borrow trick: ``(v - lo) & ~v & hi`` flags the high
     bit of every zero field; borrows only corrupt flags *above* the lowest
     genuine zero, so the least-significant set bit is always the true first
-    surviving leaf of the word.  Pure jnp — shared by the XLA engine and
-    the Pallas kernel.  Rows with no survivor (padding trees) map to 0."""
-    G = words.shape[-1]
-    lo_mask = jnp.uint32(bitmm_full_word(bits, npack))
-    hi_mask = jnp.uint32(bitmm_full_word(bits, npack) << (bits - 1))
-    v = words.astype(jnp.uint32)
+    surviving leaf of the word.  Words stay below 2^24, so int32 two's
+    complement gives the same bits as unsigned arithmetic — and int32 is
+    what Mosaic lowers (an f32 → uint32 cast is refused).  Pure jnp —
+    shared by the XLA engine and the Pallas kernel.  Rows with no
+    survivor (padding trees) map to 0."""
+    axis = axis % words.ndim
+    G = words.shape[axis]
+    lo_mask = bitmm_full_word(bits, npack)
+    hi_mask = bitmm_full_word(bits, npack) << (bits - 1)
+    v = words.astype(jnp.int32)
     t = (v - lo_mask) & ~v & hi_mask
-    lsb = t & (jnp.uint32(0) - t)
-    fidx = (jax.lax.population_count(lsb - jnp.uint32(1))
-            // jnp.uint32(bits)).astype(jnp.int32)
-    big = jnp.int32(G * npack + 1)
-    giota = jax.lax.broadcasted_iota(jnp.int32, words.shape, words.ndim - 1)
-    cand = jnp.where(t != jnp.uint32(0), giota * npack + fidx, big)
-    leaf = cand.min(axis=-1)
+    fidx = jax.lax.population_count((t & -t) - 1) // bits
+    giota = jax.lax.broadcasted_iota(jnp.int32, words.shape, axis)
+    cand = jnp.where(t != 0, giota * npack + fidx, G * npack + 1)
+    leaf = cand.min(axis=axis, keepdims=True)
     return jnp.where(leaf < n_leaves, leaf, 0)
 
 
@@ -349,7 +351,7 @@ def _bitmm_tile(bm: CompiledBitMM, X: jnp.ndarray, feat, thr, valid,
         preferred_element_type=jnp.float32)               # (Tc, B, G) MXU
     words = cleared + bias[:, None, :]
     leaf = bitmm_exit_leaf(words, bits=bm.bits, npack=bm.npack,
-                           n_leaves=bm.n_leaves).T        # (B, Tc)
+                           n_leaves=bm.n_leaves)[..., 0].T  # (B, Tc)
     vals = jnp.take_along_axis(
         lv[None], leaf[..., None, None], axis=2)[:, :, 0]  # (B, Tc, C)
     return vals.astype(acc_dtype).sum(axis=1, dtype=acc_dtype)

@@ -36,12 +36,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
-
-try:                                    # jax >= 0.6 exports it at top level
-    from jax import shard_map
-except ImportError:                     # 0.4.x
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from . import registry
 from .forest import Forest
@@ -199,14 +195,20 @@ def tree_sharded(forest: Forest, engine: str = "bitvector", *,
     mesh = Mesh(np.asarray(devs), ("trees",))
     s_specs = jax.tree.map(lambda _: P("trees"), sharded)
     r_specs = jax.tree.map(lambda _: P(), repl)
+    # place the forest on the mesh once, so a call moves only X
+    sharded = jax.device_put(sharded, NamedSharding(mesh, P("trees")))
+    repl = jax.device_put(repl, NamedSharding(mesh, P()))
 
     def _eval(sh, rp, X):
         local = rebuild(sh, rp)
         return jax.lax.psum(spec.evaluate(local, X), "trees")
 
+    # check_vma=False: engine loops carry values that start replicated
+    # (zeros) and become device-varying (tree-sharded partial sums);
+    # the engines are written per device, not for varying-axis typing
     fn = jax.jit(shard_map(_eval, mesh=mesh,
                            in_specs=(s_specs, r_specs, P()),
-                           out_specs=P()))
+                           out_specs=P(), check_vma=False))
     # the quantization metadata lives on the *original* forest; padding
     # preserves it (dataclasses.replace), so transform_inputs matches
     return ShardedPredictor(padded, spec, fn, sharded, repl, D)

@@ -30,6 +30,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .quickscorer_kernel import pallas_call
+
 NEG_INF = -1e30
 
 
@@ -85,8 +87,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 def flash_forward(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                   causal: bool = True, block_q: int = 512,
-                  block_k: int = 512, n_rep: int = 1,
-                  interpret: bool = True) -> jnp.ndarray:
+                  block_k: int = 512, n_rep: int = 1) -> jnp.ndarray:
     """q (BH, Sq, hd); k/v (BK, Sk, hd) with BH = BK·n_rep (heads of one
     batch element contiguous). Returns (BH, Sq, hd)."""
     BH, Sq, hd = q.shape
@@ -108,8 +109,9 @@ def flash_forward(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     def kv_map(b, i, j):
         return (b // n_rep, j, 0)
 
-    return pl.pallas_call(
+    return pallas_call(
         kernel,
+        semantics=("parallel", "parallel", "arbitrary"),
         grid=(BH, nq, nk),
         in_specs=[
             pl.BlockSpec((1, block_q, hd), q_map),
@@ -123,16 +125,10 @@ def flash_forward(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
             pltpu.VMEM((block_q,), jnp.float32),       # l
             pltpu.VMEM((block_q, hd), jnp.float32),    # acc
         ],
-        interpret=interpret,
-        compiler_params=dict(
-            mosaic=dict(dimension_semantics=("parallel", "parallel",
-                                             "arbitrary"))
-        ) if not interpret else None,
     )(q, k, v)
 
 
-def flash_attention_bshd(q, k, v, *, causal=True, block_q=512, block_k=512,
-                         interpret=True):
+def flash_attention_bshd(q, k, v, *, causal=True, block_q=512, block_k=512):
     """Convenience wrapper over (B, S, H, hd) q and (B, S, K, hd) k/v —
     the models/attention.py layout."""
     B, Sq, H, hd = q.shape
@@ -142,5 +138,5 @@ def flash_attention_bshd(q, k, v, *, causal=True, block_q=512, block_k=512,
     kh = k.transpose(0, 2, 1, 3).reshape(B * K, k.shape[1], hd)
     vh = v.transpose(0, 2, 1, 3).reshape(B * K, v.shape[1], hd)
     out = flash_forward(qh, kh, vh, causal=causal, block_q=block_q,
-                        block_k=block_k, n_rep=n_rep, interpret=interpret)
+                        block_k=block_k, n_rep=n_rep)
     return out.reshape(B, H, Sq, hd).transpose(0, 2, 1, 3)

@@ -1,11 +1,16 @@
 """Pallas TPU kernel: GEMM (Hummingbird-style) forest traversal — the
 beyond-paper MXU engine (DESIGN.md §2.3).
 
-Per (batch, tree) tile, entirely in VMEM:
+Per (batch, tree) tile, entirely in VMEM (tile layout: see
+``quickscorer_kernel.py``):
     S      = 1{x[feat] <= thr}            one-hot matmul feature select
-    R      = S @ A                        (Tt, Bt, N) × (Tt, N, L) MXU
-    onehot = 1{R == Bvec}                 exit-leaf equality test
-    out   += onehot @ leaf_val            (Tt, Bt, L) × (Tt, L, C) MXU
+    R_t    = A_t @ S_t                    (Lp, Np) × (Np, Bt) per tree, MXU
+    onehot = 1{R_t == 0}                  exit-leaf equality test
+    out   += onehotᵀ @ leaf_val           (Tt·Lp, Bt)ᵀ × (Tt·Lp, C) MXU
+
+Each tree's bias node always fires (threshold +inf) and its A column
+holds ``-Bvec``, the required left-edge count, so the exit leaf is the
+one whose count is exactly zero.
 """
 from __future__ import annotations
 
@@ -13,69 +18,46 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .quickscorer_kernel import mosaic_params
+from .quickscorer_kernel import (HIGHEST, accumulate, leaf_scores,
+                                 pallas_call, select_features)
 
 
-def _gemm_kernel(x_ref, feat_ref, thr_ref, a_ref, b_ref, leaf_ref, out_ref):
-    """x (Bt,d) f32 | feat (Tt,N) i32 | thr (Tt,N) f32 (padding -inf → S=0…
-    actually padding nodes need S irrelevant: A rows are zero) |
-    a (Tt,N,L) f32 | b (Tt,L) f32 (padding leaves: L+1 → never matches) |
-    leaf (Tt,L,C) f32 | out (Bt,C) f32."""
-    Bt, d = x_ref.shape
-    Tt, N = feat_ref.shape
-    L, C = leaf_ref.shape[-2:]
-
-    x = x_ref[...].astype(jnp.float32)
-    feat = feat_ref[...].reshape(Tt * N)
-    onehot_f = (jax.lax.broadcasted_iota(jnp.int32, (d, Tt * N), 0)
-                == feat[None, :]).astype(jnp.float32)
-    xsel = jnp.dot(x, onehot_f, preferred_element_type=jnp.float32)
-    S = (xsel.reshape(Bt, Tt, N) <= thr_ref[...][None]).astype(jnp.float32)
-
-    # R[t, b, l] = Σ_n S[b, t, n] A[t, n, l]
-    R = jax.lax.dot_general(
-        S, a_ref[...],
-        dimension_numbers=(((2,), (1,)), ((1,), (0,))),
-        preferred_element_type=jnp.float32)                      # (Tt, Bt, L)
-    hit = (R == b_ref[...][:, None, :]).astype(jnp.float32)      # (Tt, Bt, L)
-    part = jax.lax.dot_general(
-        hit, leaf_ref[...].astype(jnp.float32),
-        dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)                      # (Tt, Bt, C)
-    # int out_refs: per-tile f32 partial is exact (builder asserts
-    # block_t × max|leaf| < 2^24); the cross-tile sum runs in int32.
-    part = part.sum(axis=0).astype(out_ref.dtype)
-
-    @pl.when(pl.program_id(1) == 0)
-    def _init():
-        out_ref[...] = part
-
-    @pl.when(pl.program_id(1) != 0)
-    def _acc():
-        out_ref[...] += part
+def _gemm_kernel(x_ref, feat_ref, thr_ref, a_ref, leaf_ref, out_ref):
+    """x (Bt, d) f32 | feat (1, M) i32 tree-major | thr (1, M) f32 |
+    a (Tt, Lp, Np) f32 (±1 path entries, bias column -Bvec; padding
+    leaves never reach 0) | leaf (Tt·Lp, C) f32 | out (Bt, C)."""
+    Tt, Lp, Np = a_ref.shape
+    S = (select_features(x_ref[...], feat_ref[...]) <= thr_ref[...]
+         ).astype(jnp.float32).T                                  # (M, Bt)
+    hits = []
+    for t in range(Tt):
+        # HIGHEST: -Bvec outgrows bf16's exact integers on deep trees
+        R = jnp.dot(a_ref[t], S[t * Np:(t + 1) * Np], precision=HIGHEST,
+                    preferred_element_type=jnp.float32)           # (Lp, Bt)
+        hits.append(jnp.where(R == 0.0, 1.0, 0.0))
+    lhot = jnp.concatenate(hits, axis=0)                          # (Tt·Lp, Bt)
+    accumulate(out_ref, leaf_scores(leaf_ref[...], lhot))
 
 
-def gemm_forward(x, feat, thr, A, Bvec, leaf_val, *,
-                 block_b: int = 128, block_t: int = 8,
-                 interpret: bool = True, out_dtype=jnp.float32):
+def gemm_forward(x, feat, thr, A, leaf, *, block_b: int,
+                 out_dtype=jnp.float32):
+    """Tiled arrays (``ops.py``) → scores (B, C); ``B`` a multiple of
+    ``block_b``."""
     B, d = x.shape
-    T, N = feat.shape
-    L, C = leaf_val.shape[-2:]
-    grid = (B // block_b, T // block_t)
-    return pl.pallas_call(
+    nT, _, M = feat.shape
+    Tt, Lp, Np = A.shape[1:]
+    K, C = leaf.shape[1:]
+    return pallas_call(
         _gemm_kernel,
-        grid=grid,
+        semantics=("parallel", "arbitrary"),
+        grid=(B // block_b, nT),
         in_specs=[
             pl.BlockSpec((block_b, d), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_t, N), lambda i, j: (j, 0)),
-            pl.BlockSpec((block_t, N), lambda i, j: (j, 0)),
-            pl.BlockSpec((block_t, N, L), lambda i, j: (j, 0, 0)),
-            pl.BlockSpec((block_t, L), lambda i, j: (j, 0)),
-            pl.BlockSpec((block_t, L, C), lambda i, j: (j, 0, 0)),
+            pl.BlockSpec((None, 1, M), lambda i, j: (j, 0, 0)),
+            pl.BlockSpec((None, 1, M), lambda i, j: (j, 0, 0)),
+            pl.BlockSpec((None, Tt, Lp, Np), lambda i, j: (j, 0, 0, 0)),
+            pl.BlockSpec((None, K, C), lambda i, j: (j, 0, 0)),
         ],
         out_specs=pl.BlockSpec((block_b, C), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, C), out_dtype),
-        interpret=interpret,
-        compiler_params=mosaic_params("parallel", "arbitrary")
-        if not interpret else None,
-    )(x, feat, thr, A, Bvec, leaf_val)
+    )(x, feat, thr, A, leaf)

@@ -1,5 +1,15 @@
-"""jit'd wrappers around the Pallas forest kernels: padding, dtype prep,
-predictor objects matching the XLA engines' interface."""
+"""jit'd wrappers around the Pallas forest kernels: host-side tile layout,
+padding, dtype prep, predictor objects matching the XLA engines'
+interface.
+
+Tile layout (``quickscorer_kernel.py``): trees are padded to whole tiles
+of ``block_t``; each tree gets ``Np`` node slots (real nodes, then the
+bias node at slot ``N``, then inert padding) and ``Lp`` leaf rows, both
+rounded up to the 8-row sublane tile.  Per tile, node tables are one
+lane-dense row — node-major for QuickScorer (its AND runs over aligned
+per-slot blocks), tree-major for bitmm and gemm (their per-tree matmuls
+take aligned per-tree blocks).
+"""
 from __future__ import annotations
 
 import jax
@@ -13,6 +23,14 @@ from ..core.quickscorer import bitmm_full_word, bitmm_pack_arrays
 from ..core.registry import BasePredictor, ensure_feature_column
 from . import gemm_forest_kernel, quickscorer_kernel
 
+SUBLANES, LANES = 8, 128
+# scoped VMEM a kernel may use by default on v5e
+VMEM_BUDGET = 16 * 2 ** 20
+
+
+def _round_up(n: int, mult: int) -> int:
+    return -(-n // mult) * mult
+
 
 def _pad_to(x: np.ndarray, axis: int, mult: int, fill=0) -> np.ndarray:
     n = x.shape[axis]
@@ -22,12 +40,6 @@ def _pad_to(x: np.ndarray, axis: int, mult: int, fill=0) -> np.ndarray:
     widths = [(0, 0)] * x.ndim
     widths[axis] = (0, pad)
     return np.pad(x, widths, constant_values=fill)
-
-
-def _thr_pad_value(forest: Forest):
-    if np.issubdtype(forest.threshold.dtype, np.integer):
-        return np.iinfo(forest.threshold.dtype).max
-    return np.float32(np.inf)
 
 
 def bucket_rows(n: int, block_b: int) -> int:
@@ -53,6 +65,39 @@ def _out_dtype(forest: Forest, block_t: int):
             f"pallas int accumulation needs block_t*max|leaf| < 2^24, got "
             f"{block_t}*{max_abs}; lower block_t or quantize to fewer bits")
     return jnp.int32
+
+
+def _check_tiling(forest: Forest, block_b: int, block_t: int,
+                  table_rows: int) -> None:
+    """Refuse, at build time, a tiling the chip cannot compile.  On a TPU
+    the batch block is the lane dimension of every transposed tile and
+    QuickScorer's per-slot blocks are ``block_t`` sublanes, so
+    ``block_b % 128 == 0`` and ``block_t % 8 == 0``; and one tile's
+    working set must fit the scoped VMEM budget.  The CPU interpreter has
+    neither bound."""
+    if quickscorer_kernel.interpret_mode():
+        return
+    if block_b % LANES or block_t % SUBLANES:
+        raise ValueError(
+            f"pallas kernels on a TPU need block_b % {LANES} == 0 and "
+            f"block_t % {SUBLANES} == 0, got block_b={block_b}, "
+            f"block_t={block_t}")
+    d = max(forest.n_features, 1)
+    M = block_t * _round_up(forest.nodes_per_tree + 1, SUBLANES)
+    K = block_t * _round_up(forest.n_leaves, SUBLANES)
+    C = _round_up(forest.n_classes, LANES)
+    words = (2 * block_b * d               # input block, double-buffered
+             + d * M                       # one-hot feature select
+             + 4 * block_b * M             # select, predicate, transposes
+             + 2 * K * block_b             # exit-leaf one-hot
+             + 2 * K * C                   # leaf table block
+             + 2 * table_rows * M)         # node tables, per node slot
+    if 4 * words > VMEM_BUDGET:
+        raise ValueError(
+            f"pallas tile working set ~{4 * words / 2 ** 20:.1f} MiB exceeds "
+            f"the {VMEM_BUDGET / 2 ** 20:.0f} MiB scoped VMEM budget "
+            f"(block_b={block_b}, block_t={block_t}, d={d}, "
+            f"node slots per tile={M}); lower block_t or block_b")
 
 
 class _PallasPredictor(BasePredictor):
@@ -100,56 +145,117 @@ class _PallasPredictor(BasePredictor):
         return len(self._buckets)
 
 
+# --------------------------------------------------------------------------- #
+# Host-side tile layout
+# --------------------------------------------------------------------------- #
+def _node_table(forest: Forest, block_t: int, bias_thr: float):
+    """(feat, thr) as (Tp, Np) per-tree slot tables: real nodes, the bias
+    node at slot N (feature -1 reads 0, so ``bias_thr`` decides whether
+    it fires), inert padding (feature -1, threshold +inf).  Trees are
+    padded to a multiple of ``block_t``."""
+    T, N = forest.n_trees, forest.nodes_per_tree
+    Tp, Np = _round_up(T, block_t), _round_up(N + 1, SUBLANES)
+    valid = forest.feature >= 0
+    feat = np.full((Tp, Np), -1, np.int32)
+    thr = np.full((Tp, Np), np.inf, np.float32)
+    feat[:T, :N] = np.where(valid, forest.feature, -1)
+    thr[:T, :N] = np.where(valid, forest.threshold.astype(np.float32),
+                           np.float32(np.inf))
+    thr[:, N] = bias_thr
+    return feat, thr
+
+
+def _rows(a: np.ndarray, block_t: int, node_major: bool) -> np.ndarray:
+    """(Tp, Np) slot table → (n_tiles, 1, block_t × Np) lane-dense rows,
+    node-major (slot n of tree t at ``n·block_t + t``) or tree-major
+    (``t·Np + n``)."""
+    Tp, Np = a.shape
+    a = a.reshape(Tp // block_t, block_t, Np)
+    if node_major:
+        a = a.transpose(0, 2, 1)
+    return np.ascontiguousarray(a).reshape(Tp // block_t, 1, block_t * Np)
+
+
+def _leaf_table(leaf_value: np.ndarray, block_t: int) -> np.ndarray:
+    """(T, L, C) leaves → (n_tiles, block_t × Lp, C) f32, tree-major,
+    zero rows for padding leaves and padding trees."""
+    T, L, C = leaf_value.shape
+    Tp, Lp = _round_up(T, block_t), _round_up(L, SUBLANES)
+    lv = np.zeros((Tp, Lp, C), np.float32)
+    lv[:T, :L] = leaf_value
+    return lv.reshape(Tp // block_t, block_t * Lp, C)
+
+
 def _qs_arrays(forest: Forest, block_t: int):
-    """QuickScorer kernel arrays (feat, thr, masks, init_idx, leaf_val),
-    tree axis padded to ``block_t`` with inert trees (+inf thresholds →
-    no predicate fires, init 0 → leaf 0 → all-zero leaf row).  Shared by
-    the per-forest predictor and the fused cascade builder, which preps
-    each stage slice independently so stage scores match the staged
-    per-stage kernels bit-for-bit."""
-    thr_pad = _thr_pad_value(forest)
-    feat = _pad_to(np.maximum(forest.feature, 0).astype(np.int32), 0, block_t)
-    thr = forest.threshold.astype(np.float32).copy()
-    thr[forest.feature < 0] = np.float32(thr_pad) if np.isfinite(
-        np.float32(thr_pad)) else np.float32(np.inf)
-    thr = _pad_to(thr, 0, block_t, fill=np.float32(np.inf))
-    masks = _pad_to(forest.node_masks(), 0, block_t, fill=0xFFFFFFFF)
-    init_idx = _pad_to(forest.init_leafidx(), 0, block_t)           # pad: 0
-    lv = forest.leaf_value.astype(np.float32)
-    leaf_val = _pad_to(lv, 0, block_t)                              # pad: 0
-    return feat, thr, masks, init_idx, leaf_val
+    """QuickScorer tile arrays (feat, thr, masks, leaf), node-major.  The
+    bias node always fires (threshold -inf) and carries the initial
+    leafidx as its mask; padding trees get a zero one → no exit leaf →
+    leaf 0 → all-zero leaf row.  Shared by the per-forest predictor and
+    the fused cascade builder, which preps each stage slice
+    independently so stage scores match the staged per-stage kernels
+    bit-for-bit."""
+    T, N, W = forest.n_trees, forest.nodes_per_tree, forest.n_words
+    feat, thr = _node_table(forest, block_t, -np.inf)
+    Tp, Np = feat.shape
+    masks = np.full((Tp, Np, W), 0xFFFFFFFF, np.uint32)
+    masks[:T, :N] = forest.node_masks()
+    masks[:, N] = _pad_to(forest.init_leafidx(), 0, block_t)      # pad: 0
+    masks = masks.view(np.int32).reshape(Tp // block_t, block_t, Np, W)
+    masks = np.ascontiguousarray(masks.transpose(0, 3, 2, 1)).reshape(
+        Tp // block_t, W, Np * block_t)
+    return (_rows(feat, block_t, True), _rows(thr, block_t, True), masks,
+            _leaf_table(forest.leaf_value, block_t))
 
 
-def pallas_qs_predictor(forest: Forest, block_b: int = 128, block_t: int = 8,
-                        interpret: bool = True) -> _PallasPredictor:
+def _qs_table_rows(forest: Forest) -> int:
+    """VMEM rows per node slot of the QuickScorer tables: feat and thr
+    (one sublane tile each) plus the mask words."""
+    return 2 * SUBLANES + _round_up(forest.n_words, SUBLANES)
+
+
+def _device(*arrays):
+    return tuple(jnp.asarray(a) for a in arrays)
+
+
+def pallas_qs_predictor(forest: Forest, block_b: int = 128,
+                        block_t: int = 8) -> _PallasPredictor:
     """QuickScorer bitvector engine, Pallas backend."""
-    feat, thr, masks, init_idx, leaf_val = _qs_arrays(forest, block_t)
+    _check_tiling(forest, block_b, block_t, _qs_table_rows(forest))
+    feat, thr, masks, leaf = _device(*_qs_arrays(forest, block_t))
     out_dtype = _out_dtype(forest, block_t)
-
-    feat_j, thr_j = jnp.asarray(feat), jnp.asarray(thr)
-    masks_j, init_j = jnp.asarray(masks), jnp.asarray(init_idx)
-    leaf_j = jnp.asarray(leaf_val)
 
     @jax.jit
     def fn(X):
         return quickscorer_kernel.qs_forward(
-            X, feat_j, thr_j, masks_j, init_j, leaf_j,
-            block_b=block_b, block_t=block_t, interpret=interpret,
+            X, feat, thr, masks, leaf, block_b=block_b, block_t=block_t,
             out_dtype=out_dtype)
 
     return _PallasPredictor(forest, fn, block_b)
 
 
+def _cascade_arrays(forest: Forest, stages, block_t: int):
+    """Stage-concatenated QuickScorer tile arrays plus the stages' tile
+    offsets.  Each stage slice is laid out on its own (padded to whole
+    tiles), so stage scores match the staged per-stage kernels
+    bit-for-bit."""
+    from ..cascade.predictor import tree_slice
+    bounds = (0,) + tuple(stages)
+    parts = [_qs_arrays(tree_slice(forest, bounds[k], bounds[k + 1]), block_t)
+             for k in range(len(stages))]
+    stage_tiles = (0,) + tuple(
+        np.cumsum([p[0].shape[0] for p in parts]).tolist())
+    return tuple(np.concatenate([p[i] for p in parts])
+                 for i in range(4)) + (stage_tiles,)
+
+
 def pallas_fused_cascade_qs(forest: Forest, stages, policy, *,
-                            block_b: int = 128, block_t: int = 8,
-                            interpret: bool = True):
+                            block_b: int = 128, block_t: int = 8):
     """Single-kernel cascade for the bitvector engine: all stages + the
     in-kernel gate (``cascade_kernel.py``).  Returns a jitted
     ``(Xp (B, d) f32, valid (B,) bool) -> (scores (B, C) descaled,
     exit_stage (B, 1) i32)`` with ``B`` a multiple of ``block_b``;
     ``FusedCascadePredictor`` owns the batch padding and exit-count
     reduction around it."""
-    from ..cascade.predictor import tree_slice
     from . import cascade_kernel
 
     if forest.flint:
@@ -157,93 +263,97 @@ def pallas_fused_cascade_qs(forest: Forest, stages, policy, *,
             "FLInt forests are unsupported on the pallas backend: the "
             "fused cascade kernel casts input rows to f32, which cannot "
             "represent int32 FLInt keys (use backend='jax')")
-    bounds = (0,) + tuple(stages)
-    parts = [_qs_arrays(tree_slice(forest, bounds[k], bounds[k + 1]), block_t)
-             for k in range(len(stages))]
-    feat, thr, masks, init_idx, leaf_val = (
-        np.concatenate([p[i] for p in parts]) for i in range(5))
-    stage_bounds = (0,) + tuple(
-        np.cumsum([p[0].shape[0] for p in parts]).tolist())
+    _check_tiling(forest, block_b, block_t, _qs_table_rows(forest))
+    *arrays, stage_tiles = _cascade_arrays(forest, stages, block_t)
+    feat, thr, masks, leaf = _device(*arrays)
     scale = leaf_scale(forest)
-
-    feat_j, thr_j = jnp.asarray(feat), jnp.asarray(thr)
-    masks_j, init_j = jnp.asarray(masks), jnp.asarray(init_idx)
-    leaf_j = jnp.asarray(leaf_val)
 
     @jax.jit
     def fn(Xp, valid):
         scores, exit_stage = cascade_kernel.cascade_qs_forward(
-            Xp, valid.astype(jnp.float32)[:, None],
-            feat_j, thr_j, masks_j, init_j, leaf_j,
-            stage_bounds=stage_bounds, policy=policy,
-            inv_scale=1.0 / scale, block_b=block_b, interpret=interpret)
+            Xp, valid.astype(jnp.float32)[:, None], feat, thr, masks, leaf,
+            stage_tiles=stage_tiles, policy=policy, inv_scale=1.0 / scale,
+            block_b=block_b, block_t=block_t)
         # power-of-two scale: the multiply is exact on quantized forests
         return scores * jnp.float32(1.0 / scale), exit_stage
 
     return fn
 
 
+def _bitmm_arrays(forest: Forest, block_t: int):
+    """Bit-matmul tile arrays (feat, thr, packed, leaf), tree-major, and
+    the field layout (bits, npack).  The bias node always fires and adds
+    the padding-leaf fields; padding trees get every field "cleared" →
+    no survivor → leaf 0 → all-zero leaf row."""
+    packed, bias, bits, npack = bitmm_pack_arrays(forest)
+    T, N, G = packed.shape
+    feat, thr = _node_table(forest, block_t, -np.inf)
+    Tp, Np = feat.shape
+    slots = np.zeros((Tp, Np, G), np.float32)
+    slots[:T, :N] = packed
+    slots[:, N] = _pad_to(bias, 0, block_t,
+                          fill=float(bitmm_full_word(bits, npack)))
+    slots = np.ascontiguousarray(
+        slots.reshape(Tp // block_t, block_t, Np, G).transpose(0, 1, 3, 2))
+    return (_rows(feat, block_t, False), _rows(thr, block_t, False), slots,
+            _leaf_table(forest.leaf_value, block_t), bits, npack)
+
+
 def pallas_bitmm_predictor(forest: Forest, block_b: int = 128,
-                           block_t: int = 8, block_n: int = 128,
-                           interpret: bool = True) -> _PallasPredictor:
+                           block_t: int = 8) -> _PallasPredictor:
     """Bit-matmul QuickScorer engine, Pallas backend (DESIGN.md §2.4).
 
     Fuses cond-compute, the packed clear-count bit-matmul, exit-leaf
     recovery, and the leaf-table lookup in one VMEM-resident tile."""
-    packed, bias, bits, npack = bitmm_pack_arrays(forest)
-    G = packed.shape[-1]
-    feat = _pad_to(np.maximum(forest.feature, 0).astype(np.int32), 0, block_t)
-    thr = forest.threshold.astype(np.float32).copy()
-    thr[forest.feature < 0] = np.float32(np.inf)
-    thr = _pad_to(thr, 0, block_t, fill=np.float32(np.inf))
-    packed = _pad_to(packed, 0, block_t)                       # pad: 0
-    # padding trees: every leaf field biased "cleared" → no survivor →
-    # leaf 0 → all-zero leaf row → contributes nothing.
-    bias = _pad_to(bias, 0, block_t, fill=float(bitmm_full_word(bits, npack)))
-    leaf_val = _pad_to(forest.leaf_value.astype(np.float32), 0, block_t)
+    *arrays, bits, npack = _bitmm_arrays(forest, block_t)
+    _check_tiling(forest, block_b, block_t,
+                  2 * SUBLANES + _round_up(arrays[2].shape[2], SUBLANES))
+    feat, thr, packed, leaf = _device(*arrays)
     out_dtype = _out_dtype(forest, block_t)
-
-    feat_j, thr_j = jnp.asarray(feat), jnp.asarray(thr)
-    packed_j, bias_j = jnp.asarray(packed), jnp.asarray(bias)
-    leaf_j = jnp.asarray(leaf_val)
     n_leaves = forest.n_leaves
 
     @jax.jit
     def fn(X):
         return quickscorer_kernel.qs_bitmm_forward(
-            X, feat_j, thr_j, packed_j, bias_j, leaf_j,
-            bits=bits, npack=npack, n_leaves=n_leaves,
-            block_b=block_b, block_t=block_t, block_n=block_n,
-            interpret=interpret, out_dtype=out_dtype)
+            X, feat, thr, packed, leaf, bits=bits, npack=npack,
+            n_leaves=n_leaves, block_b=block_b, block_t=block_t,
+            out_dtype=out_dtype)
 
     return _PallasPredictor(forest, fn, block_b)
 
 
-def pallas_gemm_predictor(forest: Forest, block_b: int = 128, block_t: int = 8,
-                          interpret: bool = True) -> _PallasPredictor:
-    """GEMM (Hummingbird/MXU) engine, Pallas backend."""
+def _gemm_arrays(forest: Forest, block_t: int):
+    """GEMM tile arrays (feat, thr, A, leaf), tree-major.  The bias node
+    always goes left (threshold +inf) and its A column is ``-Bvec``, so a
+    leaf is hit when its count lands on exactly 0; padding leaves and
+    padding trees keep ``Bvec = L + 1`` and are never hit."""
     from ..core.baselines import compile_gemm
     g = compile_gemm(forest)                     # reuse A/Bvec construction
-    feat = _pad_to(np.asarray(g.feat), 0, block_t)
-    # padding nodes: A rows are zero so S value is irrelevant; use -inf so
-    # S=0 deterministically.
-    thr = np.asarray(g.thr, dtype=np.float32).copy()
-    thr[~np.asarray(g.valid)] = -np.inf
-    thr = _pad_to(thr, 0, block_t, fill=-np.inf)
-    A = _pad_to(np.asarray(g.A, dtype=np.float32), 0, block_t)
-    Bvec = _pad_to(np.asarray(g.Bvec, dtype=np.float32), 0, block_t,
-                   fill=forest.n_leaves + 1.0)
-    leaf_val = _pad_to(np.asarray(g.leaf_val, dtype=np.float32), 0, block_t)
-    out_dtype = _out_dtype(forest, block_t)
+    T, N, L = forest.n_trees, forest.nodes_per_tree, forest.n_leaves
+    feat, thr = _node_table(forest, block_t, np.inf)
+    Tp, Np = feat.shape
+    Lp = _round_up(L, SUBLANES)
+    A = np.zeros((Tp, Np, Lp), np.float32)
+    A[:T, :N, :L] = np.asarray(g.A)
+    A[:, N, :] = -(L + 1.0)
+    A[:T, N, :L] = -np.asarray(g.Bvec)
+    A = np.ascontiguousarray(
+        A.reshape(Tp // block_t, block_t, Np, Lp).transpose(0, 1, 3, 2))
+    return (_rows(feat, block_t, False), _rows(thr, block_t, False), A,
+            _leaf_table(np.asarray(g.leaf_val, dtype=np.float32), block_t))
 
-    feat_j, thr_j = jnp.asarray(feat), jnp.asarray(thr)
-    A_j, B_j, leaf_j = jnp.asarray(A), jnp.asarray(Bvec), jnp.asarray(leaf_val)
+
+def pallas_gemm_predictor(forest: Forest, block_b: int = 128,
+                          block_t: int = 8) -> _PallasPredictor:
+    """GEMM (Hummingbird/MXU) engine, Pallas backend."""
+    _check_tiling(forest, block_b, block_t,
+                  2 * SUBLANES + _round_up(forest.n_leaves, SUBLANES))
+    feat, thr, A, leaf = _device(*_gemm_arrays(forest, block_t))
+    out_dtype = _out_dtype(forest, block_t)
 
     @jax.jit
     def fn(X):
         return gemm_forest_kernel.gemm_forward(
-            X, feat_j, thr_j, A_j, B_j, leaf_j,
-            block_b=block_b, block_t=block_t, interpret=interpret,
-            out_dtype=out_dtype)
+            X, feat, thr, A, leaf, block_b=block_b, out_dtype=out_dtype)
 
     return _PallasPredictor(forest, fn, block_b)

@@ -1,8 +1,9 @@
 """Pure-jnp oracles for the Pallas kernels (the `ref.py` layer).
 
 These re-export the core engines' batch evaluators: the XLA engine IS the
-mathematical reference; tests assert ``pallas(interpret=True) ≈ ref ≈ numpy
-traversal oracle`` across shape/dtype sweeps.
+mathematical reference; tests assert ``pallas ≈ ref ≈ numpy traversal
+oracle`` across shape/dtype sweeps (on the CPU the Pallas kernels run in
+the interpreter).
 """
 from __future__ import annotations
 

@@ -29,6 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import core
+from ..compile_cache import setup_compile_cache
 from ..configs import get_config
 from ..data import datasets
 from ..inference.server import ForestServer, LMServer
@@ -248,6 +249,7 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    setup_compile_cache()
     out = {"forest": serve_forest, "runtime": serve_runtime,
            "lm": serve_lm}[args.mode](args)
     print(json.dumps(out, indent=2))

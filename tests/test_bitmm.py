@@ -47,7 +47,7 @@ def test_pallas_bitmm_matches_scalar_qs(T, L, d, C, full, seed):
     forest = _forest(T, L, d, C, full, seed)
     X = rand_X(forest, B=8, seed=seed + 200)
     scalar = eval_scalar_numpy(forest, X)
-    pred = pallas_bitmm_predictor(forest, block_b=8, block_t=4, block_n=16)
+    pred = pallas_bitmm_predictor(forest, block_b=8, block_t=4)
     np.testing.assert_allclose(pred.predict(X), scalar, rtol=1e-4,
                                atol=1e-5)
 

@@ -506,8 +506,7 @@ def test_cascade_save_load_bitexact_with_thresholds(qclass_forest,
 def test_cascade_save_rejects_nonserializable_engine(qclass_forest,
                                                      tmp_path):
     casc = CascadePredictor(qclass_forest, CascadeSpec((8, 24)),
-                            engine="bitvector", backend="pallas",
-                            engine_kw={"interpret": True})
+                            engine="bitvector", backend="pallas")
     with pytest.raises(ValueError, match="serial_arrays"):
         io.save_predictor(casc, str(tmp_path / "x.repro.npz"))
 

@@ -151,8 +151,7 @@ def _X(forest, B=16, seed=0):
 
 
 def _compile(forest, name, backend):
-    kw = {"interpret": True} if backend == "pallas" else {}
-    return core.compile_forest(forest, engine=name, backend=backend, **kw)
+    return core.compile_forest(forest, engine=name, backend=backend)
 
 
 # --------------------------------------------------------------------------- #
@@ -304,9 +303,8 @@ def test_cascade_single_stage_is_the_engine(case, name, backend):
     forest = ADVERSARIAL[case]()
     X = _X(forest, B=12, seed=13)
     base = _compile(forest, name, backend)
-    kw = {"interpret": True} if backend == "pallas" else {}
     casc = CascadePredictor(forest, CascadeSpec((forest.n_trees,)),
-                            engine=name, backend=backend, engine_kw=kw)
+                            engine=name, backend=backend)
     np.testing.assert_array_equal(casc.predict(X), base.predict(X),
                                   err_msg=f"{case}/{name}/{backend}")
 
@@ -378,12 +376,11 @@ FIRING_THRESHOLDS = [0.0, 0.5, np.inf]
 
 
 def _casc_pair(qf, name, backend, policy):
-    kw = {"interpret": True} if backend == "pallas" else {}
     staged = CascadePredictor(qf, CascadeSpec(_mid_stages(qf), policy),
-                              engine=name, backend=backend, engine_kw=kw)
+                              engine=name, backend=backend)
     fused = FusedCascadePredictor(
         qf, CascadeSpec(_mid_stages(qf), policy, fused=True),
-        engine=name, backend=backend, engine_kw=kw)
+        engine=name, backend=backend)
     return staged, fused
 
 
@@ -579,8 +576,7 @@ def test_flint_rejected_on_pallas():
     forest = ADVERSARIAL["one_tree"]()
     with pytest.raises(ValueError, match="pallas"):
         compile_plan(forest, CompilePlan(engine="bitvector",
-                                         backend="pallas", flint=True,
-                                         engine_kw={"interpret": True}))
+                                         backend="pallas", flint=True))
 
 
 def test_flint_and_quant_mutually_exclusive():

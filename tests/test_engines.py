@@ -22,8 +22,7 @@ COMBO_IDS = [f"{n}/{b}" for n, b in COMBOS]
 
 
 def _compile(forest, name, backend):
-    kw = {"interpret": True} if backend == "pallas" else {}
-    return core.compile_forest(forest, engine=name, backend=backend, **kw)
+    return core.compile_forest(forest, engine=name, backend=backend)
 
 
 def scalar_oracle_f32(forest, X_raw):

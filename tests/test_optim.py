@@ -322,8 +322,6 @@ def test_every_pass_preserves_oracle_on_catalog(case, name):
 # -O2 through every registered engine × backend combo (acceptance)
 # --------------------------------------------------------------------------- #
 def _compile(forest, name, backend, **kw):
-    if backend == "pallas":
-        kw.setdefault("interpret", True)
     return core.compile_forest(forest, engine=name, backend=backend, **kw)
 
 

@@ -30,6 +30,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs.trace import phase
+
 
 # --------------------------------------------------------------------------- #
 # Protocols
@@ -110,7 +112,11 @@ class BasePredictor:
     """Shared engine wrapper: input quantization + jit cache + the full
     prediction surface.  ``eval_fn(compiled, X) → (B, C)`` is the engine's
     pure evaluator; ``compiled`` carries ``transform_inputs`` when the
-    forest is quantized."""
+    forest is quantized.
+
+    Every call runs the host path ``_score``, whose steps are profiler
+    spans (``repro.obs.trace.phase``); subclasses change only the
+    ``_bucket``/``_tile``/``_launch``/``_untile`` steps."""
 
     def __init__(self, compiled, eval_fn: Callable):
         self.compiled = compiled
@@ -126,11 +132,46 @@ class BasePredictor:
         """Evaluate inputs that already went through ``transform_inputs``
         — the cascade's per-stage entry point, so a K-stage cascade
         quantizes each row once instead of once per surviving stage."""
-        Xq = ensure_feature_column(np.asarray(Xq))
-        return np.asarray(self._fn(jnp.asarray(Xq)))
+        return self._score(Xq)
 
     def predict(self, X: np.ndarray) -> np.ndarray:
-        return self.predict_transformed(self.transform_inputs(X))
+        with phase("quantize", rows=len(X)):
+            Xq = self.transform_inputs(X)
+        return self.predict_transformed(Xq)
+
+    def _score(self, Xq) -> np.ndarray:
+        """Transformed rows → host scores: pad to the row bucket, copy in,
+        launch, wait, copy out.  ``repro.wait`` then ``repro.d2h`` is the
+        one sync point, the work ``np.asarray`` on the output alone does:
+        the copy to the host is queued before the wait, so it starts when
+        the program ends and not when the host sees it end."""
+        Xq = np.asarray(Xq)
+        rows = Xq.shape[0]
+        bucket = self._bucket(rows)
+        with phase("tile_pad", rows=rows, bucket=bucket):
+            Xp = self._tile(Xq, bucket)
+        with phase("h2d", bytes=Xp.nbytes):
+            x = jnp.asarray(Xp)
+        with phase("launch"):
+            y = self._launch(x)
+        with phase("wait"):
+            y.copy_to_host_async()
+            y.block_until_ready()
+        with phase("d2h", bytes=y.nbytes):
+            return self._untile(np.asarray(y), rows)
+
+    def _bucket(self, rows: int) -> int:
+        """Rows the device program takes for a ``rows``-row call."""
+        return rows
+
+    def _tile(self, Xq: np.ndarray, bucket: int) -> np.ndarray:
+        return ensure_feature_column(Xq)
+
+    def _launch(self, x):
+        return self._fn(x)
+
+    def _untile(self, out: np.ndarray, rows: int) -> np.ndarray:
+        return out
 
     def predict_class(self, X: np.ndarray) -> np.ndarray:
         return self.predict(X).argmax(axis=1)

@@ -42,7 +42,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from . import registry
 from .forest import Forest
 from .quantize import quantize_inputs
-from .registry import BasePredictor, ensure_feature_column
+from .registry import BasePredictor
 
 
 def pad_forest_trees(forest: Forest, mult: int) -> Forest:
@@ -145,13 +145,8 @@ class ShardedPredictor(BasePredictor):
     def transform_inputs(self, X: np.ndarray) -> np.ndarray:
         return quantize_inputs(self.forest, np.asarray(X))
 
-    def predict_transformed(self, Xq: np.ndarray) -> np.ndarray:
-        Xq = ensure_feature_column(np.asarray(Xq))
-        return np.asarray(self._fn(self._sharded, self._repl,
-                                   jnp.asarray(Xq)))
-
-    def predict(self, X: np.ndarray) -> np.ndarray:
-        return self.predict_transformed(self.transform_inputs(X))
+    def _launch(self, x):
+        return self._fn(self._sharded, self._repl, x)
 
 
 def tree_sharded(forest: Forest, engine: str = "bitvector", *,

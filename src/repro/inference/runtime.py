@@ -45,6 +45,7 @@ See docs/SERVING.md for the architecture and the warmup contract.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import os
 import re
@@ -62,7 +63,7 @@ from ..obs.log import get_logger
 from ..obs.metrics import MetricsRegistry, get_registry
 from ..obs.retrace import CompileWatch
 from ..obs.serving import ServingMetrics
-from ..obs.trace import Span
+from ..obs.trace import Span, collect, phase
 from .server import MicroBatcher, Request, ServerStats
 
 _LOG = get_logger("serving")
@@ -499,53 +500,56 @@ class ServingRuntime:
         before stamping ``done_s``, stats + exit accounting) plus
         bucket padding, the adaptive controller, and — when
         observability is on — the phase span / metric / retrace hooks.
-        ``done_s`` semantics are unchanged: the instrumentation reuses
-        the timestamps the dispatch path already takes."""
+        The batch runs inside the profiler span ``repro.batch``; with
+        observability on, a ``collect()`` turns its ``repro.form`` /
+        ``repro.pad`` spans and the predictor's own into ``form_ms``,
+        ``pad_ms`` and the sub-phases of ``compute_ms``.  ``done_s``,
+        ``compute_ms`` (the whole predictor call) and ``sync_ms`` (the
+        wait on what it returned) come from the dispatch path's own
+        timestamps, with observability on or off."""
         if not reqs:
             return []
         o = self._obs if (self._obs is not None
                           and self._obs.enabled) else None
-        t_form = time.perf_counter()
-        X = np.stack([r.payload for r in reqs])
         n = len(reqs)
-        bucket = n
-        t0 = time.perf_counter()
-        try:
-            if t.pad_buckets:
-                bucket = bucket_batch(n)
-                if bucket > n:
-                    # zero rows: row-independent traversal, sliced off
-                    # before anything observable (conformance-tested)
-                    Xp = np.zeros((bucket,) + X.shape[1:], dtype=X.dtype)
-                    Xp[:n] = X
-                    X = Xp
-            t_pad = time.perf_counter()
-            scores = t.predictor.predict(X)
-            t_compute = time.perf_counter()
-            jax.block_until_ready(scores)        # async dispatch honesty
-            scores = np.asarray(scores)[:n]
-            t_sync = time.perf_counter()
-        except Exception as e:                   # noqa: BLE001 — resolve,
-            err_done = now_s + (time.perf_counter() - t0)
-            for r in reqs:                       # don't kill the worker
-                r.done_s = err_done
-            if o is not None:                    # spans before futures:
-                self._observe_error(o, t, reqs, now_s, bucket, e)
-            for r in reqs:
-                r.future.set_exception(e)
-            return reqs
+        bucket = bucket_batch(n) if t.pad_buckets else n
+        with phase("batch", tenant=t.model_id, n=n, bucket=bucket), \
+                (collect() if o is not None
+                 else contextlib.nullcontext()) as sub:
+            with phase("form"):
+                X = np.stack([r.payload for r in reqs])
+            t0 = time.perf_counter()
+            try:
+                with phase("pad"):
+                    if bucket > n:
+                        # zero rows: row-independent traversal, sliced off
+                        # before anything observable (conformance-tested)
+                        Xp = np.zeros((bucket,) + X.shape[1:], dtype=X.dtype)
+                        Xp[:n] = X
+                        X = Xp
+                t_pad = time.perf_counter()
+                scores = t.predictor.predict(X)
+                t_compute = time.perf_counter()
+                jax.block_until_ready(scores)    # async dispatch honesty
+                scores = np.asarray(scores)[:n]
+                t_sync = time.perf_counter()
+            except Exception as e:               # noqa: BLE001 — resolve,
+                err_done = now_s + (time.perf_counter() - t0)
+                for r in reqs:                   # don't kill the worker
+                    r.done_s = err_done
+                if o is not None:                # spans before futures:
+                    self._observe_error(o, t, reqs, now_s, bucket, e)
+                for r in reqs:
+                    r.future.set_exception(e)
+                return reqs
         done_s = now_s + (t_sync - t0)
         for r, s in zip(reqs, scores):
             r.result = s
             r.done_s = done_s
-        phases = {
-            "form_ms": (t0 - t_form) * 1e3,
-            "pad_ms": (t_pad - t0) * 1e3,
-            "compute_ms": (t_compute - t_pad) * 1e3,
-            "sync_ms": (t_sync - t_compute) * 1e3,
-        }
+        compute_ms = (t_compute - t_pad) * 1e3
+        sync_ms = (t_sync - t_compute) * 1e3
         t.stats.record_batch(reqs)
-        t.stats.record_phases(phases["compute_ms"], phases["sync_ms"])
+        t.stats.record_phases(compute_ms, sync_ms)
         exits = getattr(t.predictor, "last_exit_counts", None)
         t.stats.record_exits(exits)
         decisions: list[dict] = []
@@ -558,6 +562,11 @@ class ServingRuntime:
                 t.batcher.max_batch = t.controller.max_batch
                 t.batcher.max_wait_ms = t.controller.max_wait_ms
         if o is not None:
+            # form/pad and the predictor's sub-phases (all inside
+            # compute_ms) come from the phase spans' collector
+            phases = {"form_ms": sub.pop("form_ms"),
+                      "pad_ms": sub.pop("pad_ms"),
+                      "compute_ms": compute_ms, "sync_ms": sync_ms, **sub}
             self._observe_batch(o, t, reqs, now_s, bucket, phases,
                                 exits, decisions)
         # resolve futures last: a caller woken by wait() observes the

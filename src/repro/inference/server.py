@@ -29,6 +29,7 @@ built out of these parts — see docs/SERVING.md.
 """
 from __future__ import annotations
 
+import contextlib
 import random
 import time
 from dataclasses import dataclass, field
@@ -37,6 +38,8 @@ from typing import Any, Callable, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from ..obs.trace import collect, phase
 
 
 # --------------------------------------------------------------------------- #
@@ -139,8 +142,9 @@ class ServerStats:
     n_batches: int = 0
     batch_sizes: Reservoir = field(default_factory=Reservoir)
     latencies_ms: Reservoir = field(default_factory=Reservoir)
-    # per-batch phase breakdown: device compute (predict dispatch) vs
-    # host sync (block_until_ready + copy-out) — docs/OBSERVABILITY.md
+    # per-batch phase breakdown: the whole predictor call (host scores
+    # for every registry predictor) vs the wait on what it returned —
+    # docs/OBSERVABILITY.md
     compute_ms: Reservoir = field(default_factory=Reservoir)
     sync_ms: Reservoir = field(default_factory=Reservoir)
     # cascade serving: cumulative per-stage exit counts (empty unless the
@@ -157,7 +161,7 @@ class ServerStats:
             r.latency_ms for r in reqs if r.latency_ms is not None)
 
     def record_phases(self, compute_ms: float, sync_ms: float) -> None:
-        """Record one batch's device-compute / host-sync split."""
+        """Record one batch's predictor-call / wait split."""
         self.compute_ms.append(compute_ms)
         self.sync_ms.append(sync_ms)
 
@@ -361,16 +365,23 @@ class ForestServer:
     def _run(self, reqs: list[Request], now_s: float) -> list[Request]:
         if not reqs:                   # empty flush/drain: no-op, no stats
             return []
-        X = np.stack([r.payload for r in reqs])
-        t0 = time.perf_counter()
-        scores = self.predictor.predict(X)
-        t_compute = time.perf_counter()
-        # async dispatch: a predictor returning device arrays has only
-        # *launched* the work when predict returns — block before
-        # stamping done_s or the recorded latency understates reality
-        # (the same bug PR 6 fixed in the bench loops)
-        jax.block_until_ready(scores)
-        t_sync = time.perf_counter()
+        o = self._obs if (self._obs is not None and self._obs.enabled) \
+            else None
+        n = len(reqs)
+        # the predictor's own phase spans land in ``sub`` when obs is on
+        with phase("batch", tenant=self.obs_label, n=n, bucket=n), \
+                (collect() if o is not None
+                 else contextlib.nullcontext()) as sub:
+            with phase("form"):
+                X = np.stack([r.payload for r in reqs])
+            t0 = time.perf_counter()
+            scores = self.predictor.predict(X)
+            t_compute = time.perf_counter()
+            # a predictor returning device arrays has only *launched* the
+            # work when predict returns — block before stamping done_s or
+            # the recorded latency understates reality
+            jax.block_until_ready(scores)
+            t_sync = time.perf_counter()
         # completion on the caller's clock: virtual arrival time + real
         # compute time (keeps latency stats consistent under virtual clocks)
         done_s = (now_s if now_s is not None else t0) + (t_sync - t0)
@@ -386,14 +397,13 @@ class ForestServer:
         # per-stage exit fractions of the served traffic
         exits = getattr(self.predictor, "last_exit_counts", None)
         self.stats.record_exits(exits)
-        o = self._obs
-        if o is not None and o.enabled:
+        if o is not None:
             tid = self.obs_label
             o.batches_total.labels(tenant=tid).inc()
-            o.batch_size.labels(tenant=tid).observe(float(len(reqs)))
-            o.phase_ms.labels(tenant=tid, phase="compute_ms").observe(
-                compute_ms)
-            o.phase_ms.labels(tenant=tid, phase="sync_ms").observe(sync_ms)
+            o.batch_size.labels(tenant=tid).observe(float(n))
+            for p, v in dict(sub, compute_ms=compute_ms,
+                             sync_ms=sync_ms).items():
+                o.phase_ms.labels(tenant=tid, phase=p).observe(v)
             req_ctr = o.requests_total.labels(tenant=tid)
             lat_hist = o.latency_ms.labels(tenant=tid)
             queue_hist = o.phase_ms.labels(tenant=tid, phase="queue_ms")

@@ -101,8 +101,8 @@ def _check_tiling(forest: Forest, block_b: int, block_t: int,
 
 
 class _PallasPredictor(BasePredictor):
-    """Kernel-backed predictor on the shared base: overrides the predict
-    path for batch bucketing/padding, inherits predict_class/proba."""
+    """Kernel-backed predictor on the shared base: overrides the host
+    path's bucketing/padding and descale steps, inherits the rest."""
 
     def __init__(self, forest: Forest, fn, block_b: int):
         if forest.flint:
@@ -122,21 +122,20 @@ class _PallasPredictor(BasePredictor):
         return quantize_inputs(self.forest,
                                np.asarray(X)).astype(np.float32)
 
-    def predict_transformed(self, Xq: np.ndarray) -> np.ndarray:
+    def _bucket(self, rows: int) -> int:
+        return bucket_rows(rows, self.block_b)
+
+    def _tile(self, Xq: np.ndarray, bucket: int) -> np.ndarray:
         # kernels take f32 rows; coerce here so cascade stages can feed
         # the shared pre-quantized (int) matrix without a per-stage cast
         Xq = ensure_feature_column(np.asarray(Xq, dtype=np.float32))
-        B = Xq.shape[0]
-        bucket = bucket_rows(B, self.block_b)
         self._buckets.add(bucket)
-        Xp = _pad_to(Xq, 0, bucket)
-        out = np.asarray(self._fn(jnp.asarray(Xp)))
+        return _pad_to(Xq, 0, bucket)
+
+    def _untile(self, out: np.ndarray, rows: int) -> np.ndarray:
         # int-accum kernels return int32 totals; the f32 cast + pow2
         # descale matches the XLA engines' rounding bit-for-bit
-        return out[:B].astype(np.float32) / self.leaf_scale
-
-    def predict(self, X: np.ndarray) -> np.ndarray:
-        return self.predict_transformed(self.transform_inputs(X))
+        return out[:rows].astype(np.float32) / self.leaf_scale
 
     @property
     def n_compiles(self) -> int:

@@ -10,7 +10,9 @@ This package makes that stream observable (docs/OBSERVABILITY.md):
     histograms (``Reservoir``-backed), per-tenant labels, process-wide
     default instance, near-zero cost when disabled;
   * ``obs.trace``    — per-request spans (queue/form/pad/compute/sync
-    phases) in a bounded ring buffer, retrievable as JSON;
+    phases, and the predictor's sub-phases) in a bounded ring buffer,
+    retrievable as JSON; ``phase``, the one helper that writes a
+    ``repro.*`` profiler span and feeds those phases;
   * ``obs.retrace``  — jit trace-cache watchers: post-warmup compiles
     surface as anomalies instead of silent latency spikes;
   * ``obs.log``      — structured ``key=value`` logger for the launch
@@ -31,12 +33,14 @@ from .metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                       get_registry, set_default_registry)
 from .retrace import CompileWatch, fn_cache_size, jit_cache_size
 from .serving import METRIC_CATALOG, ServingMetrics
-from .trace import PHASES, Span, TraceBuffer
+from .trace import (PHASES, PREDICTOR_PHASES, Span, TraceBuffer, collect,
+                    phase)
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry",
     "get_registry", "set_default_registry",
-    "Span", "TraceBuffer", "PHASES",
+    "Span", "TraceBuffer", "PHASES", "PREDICTOR_PHASES", "phase",
+    "collect",
     "CompileWatch", "fn_cache_size", "jit_cache_size",
     "StructLogger", "get_logger", "set_level",
     "MetricsServer", "json_snapshot",

@@ -40,7 +40,8 @@ METRIC_CATALOG = {
     "repro_phase_ms": (
         "histogram", ("tenant", "phase"),
         "Per-phase request latency breakdown "
-        "(queue/form/pad/compute/sync), ms"),
+        "(queue/form/pad/compute/sync, and the predictor's sub-phases "
+        "of compute), ms"),
     "repro_queue_depth": (
         "gauge", ("tenant",),
         "Requests waiting in the tenant's micro-batcher queue"),

@@ -170,10 +170,10 @@ class CascadePredictor:
         the retrace-detection surface (``repro.obs.retrace``): a growth
         after serving warmup means some stage saw a cold shape.  ``None``
         when no stage exposes a cache (monitoring degrades to no-op)."""
-        from ..obs.retrace import fn_cache_size
+        from ..obs.retrace import jit_cache_size
         total, found = 0, False
         for p in self.stage_predictors:
-            size = fn_cache_size(getattr(p, "_fn", None))
+            size = jit_cache_size(p)
             if size is not None:
                 total, found = total + size, True
         return total if found else None

@@ -221,14 +221,38 @@ def flint_forest(forest: Forest) -> Forest:
     return out
 
 
+def flint_value(key: np.ndarray) -> np.ndarray:
+    """Inverse of ``flint_key`` on the keys of non-NaN floats: int32 keys
+    (any integer dtype, in int32 range) back to their f32 values."""
+    k = np.asarray(key).astype(np.int32)
+    return (k ^ ((k >> 31) & np.int32(0x7FFFFFFF))).view(np.float32)
+
+
+def select_columns(forest: Forest, X: np.ndarray) -> np.ndarray:
+    """Full-width rows → the IR's columns: the optimizer's column remap
+    (``feat_map``, if the ``drop_unused_features`` pass ran), else ``X``."""
+    if forest.feat_map is None:
+        return X
+    return np.asarray(X)[:, np.asarray(forest.feat_map, dtype=np.int64)]
+
+
+def _grid(forest: Forest, X: np.ndarray, lo: np.ndarray,
+          hi: np.ndarray) -> np.ndarray:
+    """Values ``X`` of features whose ranges are ``lo``/``hi`` onto a
+    quantized forest's fixed-point grid: the one arithmetic of
+    ``quantize_inputs`` and ``input_cutoffs``."""
+    q = np.floor(forest.quant_scale * normalize_features(X, lo, hi))
+    imax = 2 ** (forest.quant_bits - 1) - 1
+    return np.clip(q, -imax - 1, imax).astype(forest.threshold.dtype)
+
+
 def quantize_inputs(forest: Forest, X: np.ndarray) -> np.ndarray:
     """Apply the forest's stored input transform to raw full-width rows:
     the optimizer's column remap (``feat_map``, if the
     ``drop_unused_features`` pass ran) followed by normalisation +
     fixed-point grid (quantized forests) or the FLInt key map (flint
     forests).  No-op for float forests without a remap."""
-    if forest.feat_map is not None:
-        X = np.asarray(X)[:, np.asarray(forest.feat_map, dtype=np.int64)]
+    X = select_columns(forest, X)
     if forest.flint:
         return flint_key(X)
     if forest.quant_scale is None:
@@ -236,10 +260,78 @@ def quantize_inputs(forest: Forest, X: np.ndarray) -> np.ndarray:
     if not np.issubdtype(forest.threshold.dtype, np.integer):
         # leaves-only quantization: splits still float → inputs stay raw
         return X
-    Xn = normalize_features(X, forest.feat_lo, forest.feat_hi)
-    q = np.floor(forest.quant_scale * Xn)
-    imax = 2 ** (forest.quant_bits - 1) - 1
-    return np.clip(q, -imax - 1, imax).astype(forest.threshold.dtype)
+    return _grid(forest, X, forest.feat_lo, forest.feat_hi)
+
+
+# --------------------------------------------------------------------------- #
+# Threshold folding: the input grid moved into the node thresholds
+# (docs/QUANT.md "Threshold folding")
+# --------------------------------------------------------------------------- #
+def fold_bounds(forest: Forest):
+    """Per-feature clamp ``(c_lo, c_hi)``, f32, under which a quantized
+    forest's input quantization folds into its node thresholds, or
+    ``None`` where it does not fold.
+
+    ``c_lo`` is the largest f32 ≤ ``feat_lo`` and ``c_hi`` the smallest
+    f32 ≥ ``feat_hi``: every f32 below ``c_lo`` has grid value 0 as
+    ``c_lo`` has, every one above ``c_hi`` the grid's top as ``c_hi``
+    has, so clamping a row (NaN to ``c_lo``) changes none of its grid
+    values.  Nothing folds for float or FLInt splits, a forest with no
+    split, a range f32 cannot bound, or a grid step among f32's
+    subnormals (which the TPU's compares flush to zero)."""
+    if (forest.flint or forest.quant_scale is None
+            or not np.issubdtype(forest.threshold.dtype, np.integer)
+            or not (forest.feature >= 0).any()):
+        return None
+    lo = np.asarray(forest.feat_lo, dtype=np.float64)
+    hi = np.asarray(forest.feat_hi, dtype=np.float64)
+    with np.errstate(over="ignore"):
+        c_lo, c_hi = lo.astype(np.float32), hi.astype(np.float32)
+    c_lo = np.where(c_lo > lo, np.nextafter(c_lo, np.float32(-np.inf)), c_lo)
+    c_hi = np.where(c_hi < hi, np.nextafter(c_hi, np.float32(np.inf)), c_hi)
+    if not (np.isfinite(c_lo).all() and np.isfinite(c_hi).all()):
+        return None
+    sub = np.nextafter(np.finfo(np.float32).smallest_normal, np.float32(0))
+    q = _grid(forest, np.array([[-sub], [sub]]), forest.feat_lo,
+              forest.feat_hi)
+    if (q[0] != q[1]).any():
+        return None
+    return c_lo, c_hi
+
+
+def input_cutoffs(forest: Forest) -> np.ndarray:
+    """(T, N) f32 cutoffs of a quantized forest's nodes: for node
+    threshold ``q_t`` on feature ``f``, the largest f32 ``c`` whose grid
+    value is at most ``q_t``, so that for every f32 row value ``x``
+
+        quantize_inputs(x) > q_t   ⇔   x > c
+
+    (the grid never decreases in ``x``; docs/QUANT.md).  ``-inf`` where
+    no f32 is that low, ``+inf`` where every f32 is; padding nodes read
+    ``+inf``.  Found by bisection over the ordered f32 bit patterns (the
+    FLInt keys) on ``quantize_inputs``' own arithmetic, once per distinct
+    (feature, threshold): 33 steps."""
+    valid = forest.feature >= 0
+    pairs, inv = np.unique(
+        np.stack([forest.feature[valid],
+                  forest.threshold[valid].astype(np.int64)]),
+        axis=1, return_inverse=True)
+    f, q_t = pairs
+    kmin, kmax = (int(k) for k in flint_key(np.array([-np.inf, np.inf])))
+    a = np.full(f.shape, kmin - 1, np.int64)  # largest key known ≤ q_t
+    b = np.full(f.shape, kmax + 1, np.int64)  # smallest key known > q_t
+    while (b - a > 1).any():
+        open_ = b - a > 1
+        m = np.clip((a + b) // 2, kmin, kmax)
+        low = _grid(forest, flint_value(m), forest.feat_lo[f],
+                    forest.feat_hi[f]) <= q_t
+        a = np.where(open_ & low, m, a)
+        b = np.where(open_ & ~low, m, b)
+    c = np.where(a < kmin, np.float32(-np.inf),
+                 flint_value(np.maximum(a, kmin)))
+    out = np.full(forest.threshold.shape, np.inf, np.float32)
+    out[valid] = c[inv.reshape(-1)]
+    return out
 
 
 def leaf_scale(forest: Forest) -> float:

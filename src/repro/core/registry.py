@@ -116,7 +116,7 @@ class BasePredictor:
 
     Every call runs the host path ``_score``, whose steps are profiler
     spans (``repro.obs.trace.phase``); subclasses change only the
-    ``_bucket``/``_tile``/``_launch``/``_untile`` steps."""
+    ``_program``/``_bucket``/``_tile``/``_launch``/``_untile`` steps."""
 
     def __init__(self, compiled, eval_fn: Callable):
         self.compiled = compiled
@@ -134,8 +134,16 @@ class BasePredictor:
         quantizes each row once instead of once per surviving stage."""
         return self._score(Xq)
 
+    def folds_inputs(self, X: np.ndarray) -> bool:
+        """Whether ``transform_inputs`` leaves rows like ``X`` off the
+        forest's grid, its input quantization folded into the program's
+        node thresholds (Pallas predictors of quantized forests;
+        docs/QUANT.md "Threshold folding")."""
+        return False
+
     def predict(self, X: np.ndarray) -> np.ndarray:
-        with phase("quantize", rows=len(X)):
+        X = np.asarray(X)
+        with phase("quantize", rows=len(X), folded=self.folds_inputs(X)):
             Xq = self.transform_inputs(X)
         return self.predict_transformed(Xq)
 
@@ -148,17 +156,22 @@ class BasePredictor:
         Xq = np.asarray(Xq)
         rows = Xq.shape[0]
         bucket = self._bucket(rows)
+        fn = self._program(Xq)
         with phase("tile_pad", rows=rows, bucket=bucket):
             Xp = self._tile(Xq, bucket)
         with phase("h2d", bytes=Xp.nbytes):
             x = jnp.asarray(Xp)
         with phase("launch"):
-            y = self._launch(x)
+            y = self._launch(fn, x)
         with phase("wait"):
             y.copy_to_host_async()
             y.block_until_ready()
         with phase("d2h", bytes=y.nbytes):
             return self._untile(np.asarray(y), rows)
+
+    def _program(self, Xq: np.ndarray):
+        """The device program for transformed rows ``Xq``."""
+        return self._fn
 
     def _bucket(self, rows: int) -> int:
         """Rows the device program takes for a ``rows``-row call."""
@@ -167,8 +180,8 @@ class BasePredictor:
     def _tile(self, Xq: np.ndarray, bucket: int) -> np.ndarray:
         return ensure_feature_column(Xq)
 
-    def _launch(self, x):
-        return self._fn(x)
+    def _launch(self, fn, x):
+        return fn(x)
 
     def _untile(self, out: np.ndarray, rows: int) -> np.ndarray:
         return out

@@ -145,8 +145,8 @@ class ShardedPredictor(BasePredictor):
     def transform_inputs(self, X: np.ndarray) -> np.ndarray:
         return quantize_inputs(self.forest, np.asarray(X))
 
-    def _launch(self, x):
-        return self._fn(self._sharded, self._repl, x)
+    def _launch(self, fn, x):
+        return fn(self._sharded, self._repl, x)
 
 
 def tree_sharded(forest: Forest, engine: str = "bitvector", *,

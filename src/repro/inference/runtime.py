@@ -446,7 +446,10 @@ class ServingRuntime:
         Warmup inputs are zeros — predictions afterwards are
         bit-identical (``check_engines.py --serving`` pins this) — and
         cascade exit statistics are reset so synthetic warmup rows never
-        pollute served exit accounting.  Returns {model_id: [buckets]}."""
+        pollute served exit accounting.  Zeros go in as float64 and, for
+        a predictor that folds float32 rows into another program
+        (``folds_inputs``; docs/QUANT.md "Threshold folding"), as float32
+        too.  Returns {model_id: [buckets]}."""
         ids = [model_id] if model_id is not None else list(self._tenants)
         out = {}
         for tid in ids:
@@ -460,8 +463,12 @@ class ServingRuntime:
             d = int(getattr(forest, "n_features_in", forest.n_features))
             ladder = bucket_ladder(t.hard_max_batch)
             X = np.zeros((ladder[-1], max(d, 1)), dtype=np.float64)
-            for b in ladder:
-                jax.block_until_ready(pred.predict(X[:b]))
+            inputs = [X, X.astype(np.float32)]
+            if not getattr(pred, "folds_inputs", lambda _: False)(inputs[1]):
+                inputs.pop()
+            for X in inputs:
+                for b in ladder:
+                    jax.block_until_ready(pred.predict(X[:b]))
             getattr(pred, "reset_exit_stats", lambda: None)()
             t.warmed = tuple(ladder)
             if t.watch is not None:

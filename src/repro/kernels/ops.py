@@ -12,15 +12,19 @@ take aligned per-tree blocks).
 """
 from __future__ import annotations
 
+import threading
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from ..core.engine_select import bucket_batch
 from ..core.forest import Forest
-from ..core.quantize import leaf_scale, quantize_inputs
+from ..core.quantize import (fold_bounds, input_cutoffs, leaf_scale,
+                             quantize_inputs, select_columns)
 from ..core.quickscorer import bitmm_full_word, bitmm_pack_arrays
 from ..core.registry import BasePredictor, ensure_feature_column
+from ..obs.retrace import fn_cache_size
 from . import gemm_forest_kernel, quickscorer_kernel
 
 SUBLANES, LANES = 8, 128
@@ -100,37 +104,98 @@ def _check_tiling(forest: Forest, block_b: int, block_t: int,
             f"node slots per tile={M}); lower block_t or block_b")
 
 
+def clamp_rows(X, lo, hi):
+    """Rows into ``[lo, hi]`` per feature, NaN to ``lo``: the first op of
+    a folded program.  An inf or NaN would make the kernel's one-hot
+    feature select NaN for the whole row (``inf · 0``); the clamp keeps
+    every value's grid value (``core.quantize.fold_bounds``)."""
+    return jnp.where(jnp.isnan(X), lo, jnp.clip(X, lo, hi))
+
+
 class _PallasPredictor(BasePredictor):
     """Kernel-backed predictor on the shared base: overrides the host
-    path's bucketing/padding and descale steps, inherits the rest."""
+    path's bucketing/padding and descale steps, inherits the rest.
 
-    def __init__(self, forest: Forest, fn, block_b: int):
+    ``kernel(X, thr)`` runs the engine on f32 rows against a node
+    threshold table that ``layout(thresholds)`` lays out from (T, N)
+    thresholds.  A quantized forest has two programs, each built on
+    first use and chosen by the transformed rows' dtype: rows on its
+    integer grid (``quantize_inputs``, e.g. float64 rows or a cascade's
+    shared matrix) run against its integer thresholds; rows f32 holds
+    exactly skip host quantization, are clamped (``clamp_rows``) and run
+    against the folded cutoffs (``input_cutoffs``), which make the same
+    decisions (docs/QUANT.md "Threshold folding").  Float forests have
+    the one program."""
+
+    def __init__(self, forest: Forest, kernel, layout, block_b: int):
         if forest.flint:
             raise ValueError(
                 "FLInt forests are unsupported on the pallas backend: the "
                 "kernels cast input rows to f32, which cannot represent "
                 "int32 FLInt keys (use backend='jax')")
-        # no BasePredictor.__init__: fn is already jit'd by the builders
-        # and the "compiled" state is the host forest + closure arrays
+        # no BasePredictor.__init__: the "compiled" state is the host
+        # forest + the kernel's closure arrays; programs are built lazily
         self.forest = forest
-        self._fn = fn
         self.block_b = block_b
         self.leaf_scale = leaf_scale(forest)
-        self._buckets: set[int] = set()
+        self._kernel, self._layout = kernel, layout
+        self._bounds = fold_bounds(forest)
+        self._programs: dict[bool, object] = {}      # folded -> jitted fn
+        self._lock = threading.Lock()
+        self._variants: set = set()                  # (bucket, program)
+
+    @property
+    def _fn(self):
+        """The program on the forest's own thresholds (the fused cascade
+        traces it with quantized rows)."""
+        return self._program_for(False)
+
+    def _program_for(self, folded: bool):
+        with self._lock:
+            fn = self._programs.get(folded)
+            if fn is None:
+                fn = self._programs[folded] = self._build(folded)
+        return fn
+
+    def _build(self, folded: bool):
+        kernel = self._kernel
+        if not folded:
+            thr = jnp.asarray(self._layout(self.forest.threshold))
+            return jax.jit(lambda X: kernel(X, thr))
+        thr = jnp.asarray(self._layout(input_cutoffs(self.forest)))
+        lo, hi = (jnp.asarray(b) for b in self._bounds)
+        return jax.jit(lambda X: kernel(clamp_rows(X, lo, hi), thr))
+
+    def folds_inputs(self, X: np.ndarray) -> bool:
+        return self._bounds is not None and np.can_cast(X.dtype, np.float32)
 
     def transform_inputs(self, X: np.ndarray) -> np.ndarray:
-        return quantize_inputs(self.forest,
-                               np.asarray(X)).astype(np.float32)
+        X = np.asarray(X)
+        if self.folds_inputs(X):
+            return np.asarray(select_columns(self.forest, X),
+                              dtype=np.float32)
+        Xq = quantize_inputs(self.forest, X)
+        if np.issubdtype(self.forest.threshold.dtype, np.integer):
+            return Xq                   # on the grid: integer dtype
+        return Xq.astype(np.float32)
+
+    def _program(self, Xq: np.ndarray):
+        return self._program_for(
+            self._bounds is not None
+            and not np.issubdtype(Xq.dtype, np.integer))
 
     def _bucket(self, rows: int) -> int:
         return bucket_rows(rows, self.block_b)
 
     def _tile(self, Xq: np.ndarray, bucket: int) -> np.ndarray:
-        # kernels take f32 rows; coerce here so cascade stages can feed
-        # the shared pre-quantized (int) matrix without a per-stage cast
+        # kernels take f32 rows; integer rows (the host path's grid, a
+        # cascade's shared pre-quantized matrix) are cast here, exactly
         Xq = ensure_feature_column(np.asarray(Xq, dtype=np.float32))
-        self._buckets.add(bucket)
         return _pad_to(Xq, 0, bucket)
+
+    def _launch(self, fn, x):
+        self._variants.add((x.shape[0], fn))
+        return fn(x)
 
     def _untile(self, out: np.ndarray, rows: int) -> np.ndarray:
         # int-accum kernels return int32 totals; the f32 cast + pow2
@@ -139,29 +204,49 @@ class _PallasPredictor(BasePredictor):
 
     @property
     def n_compiles(self) -> int:
-        """Distinct compiled kernel variants: the jit cache is keyed on the
-        padded input shape, so distinct buckets == distinct compiles."""
-        return len(self._buckets)
+        """Distinct compiled kernel variants: each program's jit cache is
+        keyed on the padded input shape, so distinct (bucket, program)
+        pairs == distinct compiles."""
+        return len(self._variants)
+
+    def trace_cache_size(self):
+        """Trace-cache entries over the programs built so far
+        (``repro.obs.retrace``), ``None`` if one hides its cache."""
+        sizes = [fn_cache_size(fn) for fn in list(self._programs.values())]
+        return None if None in sizes else sum(sizes)
 
 
 # --------------------------------------------------------------------------- #
 # Host-side tile layout
 # --------------------------------------------------------------------------- #
-def _node_table(forest: Forest, block_t: int, bias_thr: float):
-    """(feat, thr) as (Tp, Np) per-tree slot tables: real nodes, the bias
-    node at slot N (feature -1 reads 0, so ``bias_thr`` decides whether
-    it fires), inert padding (feature -1, threshold +inf).  Trees are
+def _node_table(forest: Forest, block_t: int, bias_thr: float,
+                thresholds=None):
+    """(feat, thr) as (Tp, Np) per-tree slot tables: real nodes with
+    ``thresholds`` (T, N) (default the forest's own), the bias node at
+    slot N (feature -1 reads 0, so ``bias_thr`` decides whether it
+    fires), inert padding (feature -1, threshold +inf).  Trees are
     padded to a multiple of ``block_t``."""
     T, N = forest.n_trees, forest.nodes_per_tree
     Tp, Np = _round_up(T, block_t), _round_up(N + 1, SUBLANES)
+    if thresholds is None:
+        thresholds = forest.threshold
     valid = forest.feature >= 0
     feat = np.full((Tp, Np), -1, np.int32)
     thr = np.full((Tp, Np), np.inf, np.float32)
     feat[:T, :N] = np.where(valid, forest.feature, -1)
-    thr[:T, :N] = np.where(valid, forest.threshold.astype(np.float32),
+    thr[:T, :N] = np.where(valid, thresholds.astype(np.float32),
                            np.float32(np.inf))
     thr[:, N] = bias_thr
     return feat, thr
+
+
+def _thr_layout(forest: Forest, block_t: int, bias_thr: float,
+                node_major: bool):
+    """(T, N) thresholds → the kernel's threshold rows, as
+    ``_node_table`` then ``_rows`` lay them out."""
+    return lambda thresholds: _rows(
+        _node_table(forest, block_t, bias_thr, thresholds)[1], block_t,
+        node_major)
 
 
 def _rows(a: np.ndarray, block_t: int, node_major: bool) -> np.ndarray:
@@ -220,16 +305,18 @@ def pallas_qs_predictor(forest: Forest, block_b: int = 128,
                         block_t: int = 8) -> _PallasPredictor:
     """QuickScorer bitvector engine, Pallas backend."""
     _check_tiling(forest, block_b, block_t, _qs_table_rows(forest))
-    feat, thr, masks, leaf = _device(*_qs_arrays(forest, block_t))
+    feat, _, masks, leaf = _qs_arrays(forest, block_t)
+    feat, masks, leaf = _device(feat, masks, leaf)
     out_dtype = _out_dtype(forest, block_t)
 
-    @jax.jit
-    def fn(X):
+    def kernel(X, thr):
         return quickscorer_kernel.qs_forward(
             X, feat, thr, masks, leaf, block_b=block_b, block_t=block_t,
             out_dtype=out_dtype)
 
-    return _PallasPredictor(forest, fn, block_b)
+    return _PallasPredictor(forest, kernel,
+                            _thr_layout(forest, block_t, -np.inf, True),
+                            block_b)
 
 
 def _cascade_arrays(forest: Forest, stages, block_t: int):
@@ -307,18 +394,20 @@ def pallas_bitmm_predictor(forest: Forest, block_b: int = 128,
     *arrays, bits, npack = _bitmm_arrays(forest, block_t)
     _check_tiling(forest, block_b, block_t,
                   2 * SUBLANES + _round_up(arrays[2].shape[2], SUBLANES))
-    feat, thr, packed, leaf = _device(*arrays)
+    feat, _, packed, leaf = arrays
+    feat, packed, leaf = _device(feat, packed, leaf)
     out_dtype = _out_dtype(forest, block_t)
     n_leaves = forest.n_leaves
 
-    @jax.jit
-    def fn(X):
+    def kernel(X, thr):
         return quickscorer_kernel.qs_bitmm_forward(
             X, feat, thr, packed, leaf, bits=bits, npack=npack,
             n_leaves=n_leaves, block_b=block_b, block_t=block_t,
             out_dtype=out_dtype)
 
-    return _PallasPredictor(forest, fn, block_b)
+    return _PallasPredictor(forest, kernel,
+                            _thr_layout(forest, block_t, -np.inf, False),
+                            block_b)
 
 
 def _gemm_arrays(forest: Forest, block_t: int):
@@ -347,12 +436,14 @@ def pallas_gemm_predictor(forest: Forest, block_b: int = 128,
     """GEMM (Hummingbird/MXU) engine, Pallas backend."""
     _check_tiling(forest, block_b, block_t,
                   2 * SUBLANES + _round_up(forest.n_leaves, SUBLANES))
-    feat, thr, A, leaf = _device(*_gemm_arrays(forest, block_t))
+    feat, _, A, leaf = _gemm_arrays(forest, block_t)
+    feat, A, leaf = _device(feat, A, leaf)
     out_dtype = _out_dtype(forest, block_t)
 
-    @jax.jit
-    def fn(X):
+    def kernel(X, thr):
         return gemm_forest_kernel.gemm_forward(
             X, feat, thr, A, leaf, block_b=block_b, out_dtype=out_dtype)
 
-    return _PallasPredictor(forest, fn, block_b)
+    return _PallasPredictor(forest, kernel,
+                            _thr_layout(forest, block_t, np.inf, False),
+                            block_b)

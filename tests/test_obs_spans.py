@@ -84,13 +84,28 @@ def test_predict_writes_six_spans_in_order(build, tmp_path):
     _assert_direct_children(spans, caller, PREDICTOR_SPANS)
     args = {name: a for _, _, name, a in spans}
     bucket = 32 if hasattr(pred, "block_b") else 20
-    assert args["repro.quantize"] == {"rows": 20}
+    assert args["repro.quantize"] == {"rows": 20, "folded": 0}   # float64
     assert args["repro.tile_pad"] == {"rows": 20, "bucket": bucket}
     itemsize = 4 if hasattr(pred, "block_b") \
         else pred.transform_inputs(X).itemsize
     assert args["repro.h2d"] == {"bytes": bucket * 7 * itemsize}
     assert args["repro.d2h"]["bytes"] == bucket * 3 * 4
     assert "repro.launch" in args and "repro.wait" in args
+
+
+@pytest.mark.parametrize("dtype,folded", [("float32", 1), ("float64", 0)])
+def test_quantize_span_says_whether_rows_folded(dtype, folded, tmp_path):
+    """A Pallas predictor of a quantized forest folds float32 rows into
+    its thresholds and quantizes float64 rows on the host; the
+    ``repro.quantize`` span's ``folded`` says which (a bool, read back
+    as 0 or 1)."""
+    pred = pallas_qs_predictor(_forest(), block_b=32, block_t=4)
+    X = np.random.default_rng(4).normal(size=(20, 7)).astype(dtype)
+    want = pred.predict(X)                       # compile outside the trace
+    events = _traced(tmp_path, lambda: np.testing.assert_array_equal(
+        pred.predict(X), want))
+    (_, _, _, args), = _named(events, "repro.quantize")
+    assert args == {"rows": 20, "folded": folded}
 
 
 def test_predict_transformed_skips_quantize(tmp_path):

@@ -254,6 +254,32 @@ def test_warmup_covers_ladder_and_freezes_trace_count(qpred_pair):
     assert rt.summary("m")["n_requests"] == 40
 
 
+def test_warmup_covers_folded_program_of_float32_rows(qpred_pair):
+    """A Pallas predictor of a quantized forest runs float32 rows through
+    a program of their own (folded thresholds); warmup traces it too, so
+    float32 traffic adds no trace, and is served as the predictor
+    scores it directly."""
+    from repro.kernels.ops import pallas_qs_predictor
+    qa, *_ = qpred_pair
+    pred = pallas_qs_predictor(qa, block_b=32, block_t=4)
+    rt = ServingRuntime()
+    rt.add_model("m", pred, max_batch=8, max_wait_ms=1.0)
+    rt.warmup()
+    assert set(pred._programs) == {False, True}
+    n_traces = pred.trace_cache_size()
+    assert n_traces == 2                                 # one bucket each
+    X = np.random.default_rng(1).normal(size=(12, qa.n_features)).astype(
+        np.float32)
+    reqs = []
+    for i in range(12):
+        reqs.append(rt.submit("m", X[i], arrival_s=i * 1e-4))
+        rt.pump(now_s=i * 1e-4)
+    rt.flush(now_s=1.0)
+    assert pred.trace_cache_size() == n_traces           # no cold program
+    np.testing.assert_array_equal(np.stack([r.result for r in reqs]),
+                                  pred.predict(X))
+
+
 def test_warmup_predictions_bit_identical(qpred_pair):
     qa, _, *_ = qpred_pair
     pred = core.compile_forest(qa, engine="rapidscorer")

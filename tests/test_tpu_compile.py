@@ -152,3 +152,20 @@ def test_tile_layout_at_compiled_widths(forest):
     bias = thr.reshape(-1, slots, BLOCK_T)[:, forest.nodes_per_tree]
     assert np.all(bias == -np.inf)
     assert leaf.shape == (T // BLOCK_T, BLOCK_T * L, C)
+
+
+def test_folded_qs_program_compiles(one_chip, forest, compiled_mode):
+    """The program float32 rows of a quantized forest take (the clamp,
+    then the QuickScorer kernel against the folded cutoffs) compiles:
+    the predictor's own program, lowered for the described chip."""
+    import jax
+    import jax.numpy as jnp
+    from repro import core
+    from repro.kernels.ops import pallas_qs_predictor
+    calib = np.random.default_rng(0).normal(size=(4096, D))
+    qf = core.quantize_forest(forest, calib)
+    pred = pallas_qs_predictor(qf, block_b=BLOCK_B, block_t=BLOCK_T)
+    assert pred.folds_inputs(np.zeros((1, D), np.float32))
+    x = jax.ShapeDtypeStruct((B, D), jnp.float32, sharding=one_chip)
+    c = pred._program_for(True).lower(x).compile()
+    assert "tpu_custom_call" in c.as_text()

@@ -21,7 +21,10 @@ leaves, the mnist signature: d=784, 10 classes), float and int16:
            Poisson requests; every score matches the oracle and no
            compile happens after warmup;
   cascade  the fused single-kernel Pallas cascade equals the staged
-           loop bit for bit, scores and exit counts.
+           loop bit for bit, scores and exit counts;
+  wide     the three compiled Pallas kernels at 500 trees × 255 leaves
+           (8 mask words a node), d=136, 4096 rows, float32, agree with
+           ``Forest.predict_oracle``.
 
 ``--chips 4`` runs only the tree-sharded path: 10240 trees × 64 leaves,
 d=136, int16, 4096 rows, sharded over four chips against the unsharded
@@ -89,12 +92,12 @@ def q_oracle(qf, X) -> np.ndarray:
             / core.leaf_scale(qf)).astype(np.float32)
 
 
-def check(tag: str, got, ref, exact: bool) -> None:
+def check(tag: str, got, ref, exact: bool, atol: float = ATOL) -> None:
     got = np.asarray(got)
     if exact:
         np.testing.assert_array_equal(got, ref, err_msg=tag)
     else:
-        np.testing.assert_allclose(got, ref, rtol=RTOL, atol=ATOL,
+        np.testing.assert_allclose(got, ref, rtol=RTOL, atol=atol,
                                    err_msg=tag)
 
 
@@ -233,6 +236,33 @@ def phase_cascade(qf, X) -> None:
         f"exits per stage {exits.tolist()}")
 
 
+def phase_wide(T=500, L=255, d=136, B=4096) -> None:
+    """The Pallas kernels on trees wider than 64 leaves, at the shape of
+    LightGBM's published ranker (num_leaves 255, 500 iterations, the 136
+    MSLR-WEB30K features), leaves N(0, 0.1^2) as a boosting step's."""
+    import dataclasses
+    f = core.random_forest_ir(T, L, d, n_classes=1, seed=SEED, full=False)
+    f = dataclasses.replace(f, leaf_value=0.1 * f.leaf_value)
+    X = np.random.default_rng(SEED).standard_normal((B, d), dtype=np.float32)
+    ref = f.predict_oracle(X)
+    # float32 sums of T leaves in another order than the float64 oracle:
+    # each addition rounds by at most 2^-24 of a partial sum, and a
+    # partial sum is below the sum over trees of each tree's largest leaf
+    top = np.abs(f.leaf_value).max(axis=(1, 2))
+    atol = T * 2.0 ** -24 * float(top.sum())
+    log(f"wide: {T} trees × {L} leaves ({f.n_words} mask words), d={d}, "
+        f"max depth {f.max_depth}; batch {B}; atol {atol:.3g}")
+
+    def engine(spec):
+        pred = core.compile_forest(f, engine=spec.name, backend=spec.backend)
+        check(f"wide {spec.tune_name}", pred.predict(X), ref, False, atol)
+        if not pallas_compiled(pred, X):
+            raise AssertionError(f"{spec.tune_name}: kernel not compiled")
+
+    run_all([(f"wide {spec.tune_name}", lambda spec=spec: engine(spec))
+             for spec in registry.specs("pallas")])
+
+
 def one_chip(T=1024, L=64, d=784, C=10, B=2048) -> None:
     forest = rf_forest(T, L, d, C)
     X = np.random.default_rng(SEED).normal(size=(B, d)).astype(np.float32)
@@ -241,7 +271,8 @@ def one_chip(T=1024, L=64, d=784, C=10, B=2048) -> None:
         f"max depth {forest.max_depth}; batch {B}")
     run_all([("phase engines", lambda: phase_engines(forest, qf, X)),
              ("phase serving", lambda: phase_serving(qf, X)),
-             ("phase cascade", lambda: phase_cascade(qf, X))])
+             ("phase cascade", lambda: phase_cascade(qf, X)),
+             ("phase wide trees", phase_wide)])
 
 
 # --------------------------------------------------------------------------- #

@@ -42,6 +42,7 @@ backend, where they compile — or explicitly via
 """
 from __future__ import annotations
 
+import gc
 import hashlib
 import json
 import os
@@ -90,6 +91,10 @@ def _autotune_metrics():
         "benched": reg.counter(
             "repro_autotune_candidates_benched_total",
             "Candidate predictors built and timed by autotune sweeps"),
+        "dropped": reg.counter(
+            "repro_autotune_candidates_dropped_total",
+            "Candidates left out of a sweep because their build or first "
+            "call could not allocate", labels=("candidate",)),
         "winner": reg.gauge(
             "repro_autotune_winner_info",
             "Autotune winner per shape key (info gauge: value is "
@@ -398,6 +403,31 @@ def _bench_candidate(factory: Callable, X: np.ndarray,
     return pred, compile_s, _bench_once(pred, X, repeats)
 
 
+def _out_of_memory(e: BaseException) -> bool:
+    """Whether ``e`` is an allocation failure: the host's ``MemoryError``
+    or XLA's ``RESOURCE_EXHAUSTED`` (an out-of-memory ``JaxRuntimeError``
+    on the device), raised while compiling or running a candidate."""
+    if isinstance(e, MemoryError):
+        return True
+    import jax
+    msg = str(e)
+    return "RESOURCE_EXHAUSTED" in msg or (
+        isinstance(e, jax.errors.JaxRuntimeError)
+        and "out of memory" in msg.lower())
+
+
+def _bench_or_drop(factory: Callable, X: np.ndarray, repeats: int) -> tuple:
+    """``(_bench_candidate's result, None)``, or ``(None, the first line
+    of the allocation error)`` for a candidate that cannot allocate;
+    any other error propagates."""
+    try:
+        return _bench_candidate(factory, X, repeats), None
+    except (MemoryError, RuntimeError) as e:   # JaxRuntimeError included
+        if not _out_of_memory(e):
+            raise
+        return None, (str(e).splitlines() or [type(e).__name__])[0][:200]
+
+
 def _layout_tag(kw: dict) -> str:
     return ",".join(f"{k}={kw[k]}" for k in sorted(kw))
 
@@ -683,7 +713,12 @@ def choose(forest: Forest, batch: int, *, engines=None,
     When ``cache_path`` is omitted it defaults to ``$REPRO_ENGINE_CACHE``
     (or ``~/.cache/repro/engine_cache.json``); ``cache_path=None``
     disables the disk layer entirely.  ``force=True`` re-benchmarks
-    regardless of any cached entry.
+    regardless of any cached entry.  A candidate whose build or first
+    call cannot allocate (``_out_of_memory``) is logged
+    (``candidate_dropped``), counted in
+    ``repro_autotune_candidates_dropped_total`` and timed (and cached)
+    at infinity, so it never wins; ``MemoryError`` only when no
+    candidate could allocate.
 
     ``mode="predict"`` (alias ``"-Os"``, docs/AUTOTUNE.md) is the
     zero-shot path: after the cache layers miss, a learned cost model
@@ -871,7 +906,19 @@ def choose(forest: Forest, batch: int, *, engines=None,
     best_pred, best_t = None, float("inf")
     sweep_t0 = time.perf_counter()
     for name, members in reps.items():
-        pred, c_s, b_s = _bench_candidate(factories[name], X, repeats)
+        done, error = _bench_or_drop(factories[name], X, repeats)
+        if done is None:
+            # a candidate that cannot allocate at this shape is timed at
+            # infinity: it never wins, and the cache remembers it was tried
+            _LOG.warning("candidate_dropped", key=key, candidate=name,
+                         error=error)
+            if obs is not None:
+                obs["dropped"].labels(candidate=name).inc()
+            for m in members:
+                fresh[m] = float("inf")
+            gc.collect()            # free what the failed build held
+            continue
+        pred, c_s, b_s = done
         for m in members:
             fresh[m] = b_s
             fresh_compile[m] = c_s
@@ -883,6 +930,9 @@ def choose(forest: Forest, batch: int, *, engines=None,
     # partial-coverage miss: cached timings fill in the engines we skipped
     timings = {e: fresh.get(e, cached.get(e)) for e in candidates}
     winner = min(timings, key=timings.get)
+    if timings[winner] == float("inf"):
+        raise MemoryError(f"no candidate for {key} could allocate: "
+                          f"{sorted(timings)}")
     if obs is not None:
         obs["sweeps"].inc()
         obs["sweep_s"].observe(sweep_s)
@@ -902,7 +952,8 @@ def choose(forest: Forest, batch: int, *, engines=None,
         entry = {"engine": min(fresh, key=fresh.get), "timings": fresh,
                  "compile_s": fresh_compile,
                  "bench_us": {c: t / bucket * 1e6
-                              for c, t in fresh.items()},
+                              for c, t in fresh.items() if c in
+                              fresh_compile},
                  "meta": shape_meta(forest, bucket, n_devices),
                  "v": SCHEMA_VERSION}
         _MEM_CACHE[key] = _merge_entry(prior, entry)

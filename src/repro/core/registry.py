@@ -22,6 +22,7 @@ engine-name list anywhere in the tree (see docs/DESIGN.md §4).
 """
 from __future__ import annotations
 
+import functools
 import importlib
 from dataclasses import dataclass, field, replace
 from typing import Callable, Optional, Protocol, runtime_checkable
@@ -30,6 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs import metrics as _obs_metrics
 from ..obs.trace import phase
 
 
@@ -116,7 +118,14 @@ class BasePredictor:
 
     Every call runs the host path ``_score``, whose steps are profiler
     spans (``repro.obs.trace.phase``); subclasses change only the
-    ``_program``/``_bucket``/``_tile``/``_launch``/``_untile`` steps."""
+    ``_program``/``_bucket``/``_tile``/``_launch``/``_untile`` steps
+    and what ``launch_args`` says of the shape they run at."""
+
+    #: the autotuner's name of the engine (``EngineSpec.tune_name``),
+    #: stamped by ``EngineSpec.builder``; "" for a hand-built predictor
+    tune_name = ""
+    #: a tiled engine's tile, for ``launch_args``
+    tile: Optional[dict] = None
 
     def __init__(self, compiled, eval_fn: Callable):
         self.compiled = compiled
@@ -161,13 +170,27 @@ class BasePredictor:
             Xp = self._tile(Xq, bucket)
         with phase("h2d", bytes=Xp.nbytes):
             x = jnp.asarray(Xp)
-        with phase("launch"):
+        args = self.launch_args
+        with phase("launch", **args):
             y = self._launch(fn, x)
         with phase("wait"):
             y.copy_to_host_async()
             y.block_until_ready()
         with phase("d2h", bytes=y.nbytes):
-            return self._untile(np.asarray(y), rows)
+            out = self._untile(np.asarray(y), rows)
+        count_tree_rows(args["engine"], rows * args["trees"])
+        return out
+
+    @functools.cached_property
+    def launch_args(self) -> dict:
+        """The ``repro.launch`` span's arguments: the engine, the
+        forest's trees and mask words (``Forest.n_words``, the leaf
+        bitvector's 32-bit words), and a tiled engine's ``tile``."""
+        f = self.host_forest()
+        return {"engine": self.tune_name or type(self).__name__,
+                "trees": 0 if f is None else int(f.n_trees),
+                "words": 0 if f is None else int(f.n_words),
+                **(self.tile or {})}
 
     def _program(self, Xq: np.ndarray):
         """The device program for transformed rows ``Xq``."""
@@ -208,6 +231,16 @@ class BasePredictor:
         return normalize_scores(self.predict(X), votes=votes)
 
 
+def count_tree_rows(engine: str, n: int) -> None:
+    """Add ``n`` scored rows × trees to ``repro_tree_rows_total{engine}``
+    on the default registry, when observability is on."""
+    reg = _obs_metrics.get_registry()
+    if reg.enabled:
+        reg.counter("repro_tree_rows_total",
+                    "Rows x trees scored by predictors",
+                    labels=("engine",)).labels(engine=engine).inc(float(n))
+
+
 # --------------------------------------------------------------------------- #
 # The registry
 # --------------------------------------------------------------------------- #
@@ -241,7 +274,18 @@ class EngineSpec:
     doc: str = ""
 
     def builder(self) -> Callable:
-        """Resolve the (forest, **kw) → predictor callable."""
+        """The (forest, **kw) → predictor callable; what it builds
+        carries this spec's ``tune_name``."""
+        build = self._resolve()
+
+        def stamped(forest, **kw):
+            pred = build(forest, **kw)
+            pred.tune_name = self.tune_name
+            return pred
+
+        return stamped
+
+    def _resolve(self) -> Callable:
         if self.build is not None:
             return self.build
         if self.deferred is not None:

@@ -136,6 +136,7 @@ class ShardedPredictor(BasePredictor):
         # mesh, not a single compiled object.
         self.forest = forest
         self.engine = spec.name
+        self.tune_name = spec.tune_name
         self.spec = spec
         self.n_devices = n_devices
         self._sharded = sharded

@@ -127,7 +127,8 @@ class _PallasPredictor(BasePredictor):
     decisions (docs/QUANT.md "Threshold folding").  Float forests have
     the one program."""
 
-    def __init__(self, forest: Forest, kernel, layout, block_b: int):
+    def __init__(self, forest: Forest, kernel, layout, block_b: int,
+                 tile: dict):
         if forest.flint:
             raise ValueError(
                 "FLInt forests are unsupported on the pallas backend: the "
@@ -137,6 +138,7 @@ class _PallasPredictor(BasePredictor):
         # forest + the kernel's closure arrays; programs are built lazily
         self.forest = forest
         self.block_b = block_b
+        self.tile = tile
         self.leaf_scale = leaf_scale(forest)
         self._kernel, self._layout = kernel, layout
         self._bounds = fold_bounds(forest)
@@ -301,6 +303,15 @@ def _device(*arrays):
     return tuple(jnp.asarray(a) for a in arrays)
 
 
+def _tile(feat, leaf) -> dict:
+    """A predictor's tile, from its node rows ``(tiles, 1, slots)`` and
+    leaf table ``(tiles, rows, C)``: node slots and leaf rows per tree
+    tile, and the tree tiles (``BasePredictor.launch_args``)."""
+    return {"node_slots": int(feat.shape[-1]),
+            "leaf_rows": int(leaf.shape[1]),
+            "tree_tiles": int(feat.shape[0])}
+
+
 def pallas_qs_predictor(forest: Forest, block_b: int = 128,
                         block_t: int = 8) -> _PallasPredictor:
     """QuickScorer bitvector engine, Pallas backend."""
@@ -316,7 +327,7 @@ def pallas_qs_predictor(forest: Forest, block_b: int = 128,
 
     return _PallasPredictor(forest, kernel,
                             _thr_layout(forest, block_t, -np.inf, True),
-                            block_b)
+                            block_b, _tile(feat, leaf))
 
 
 def _cascade_arrays(forest: Forest, stages, block_t: int):
@@ -407,7 +418,7 @@ def pallas_bitmm_predictor(forest: Forest, block_b: int = 128,
 
     return _PallasPredictor(forest, kernel,
                             _thr_layout(forest, block_t, -np.inf, False),
-                            block_b)
+                            block_b, _tile(feat, leaf))
 
 
 def _gemm_arrays(forest: Forest, block_t: int):
@@ -446,4 +457,4 @@ def pallas_gemm_predictor(forest: Forest, block_b: int = 128,
 
     return _PallasPredictor(forest, kernel,
                             _thr_layout(forest, block_t, np.inf, False),
-                            block_b)
+                            block_b, _tile(feat, leaf))

@@ -383,3 +383,85 @@ def test_garbage_entries_dropped_but_valid_entries_kept(small_forest,
         rewritten = json.load(f)
     assert "corrupt_key" not in rewritten
     assert good.key in rewritten
+
+
+# --------------------------------------------------------------------------- #
+# candidates that cannot allocate: dropped from the sweep, never fatal
+# --------------------------------------------------------------------------- #
+def _oom():
+    import jax
+    return jax.errors.JaxRuntimeError(
+        "RESOURCE_EXHAUSTED: Out of memory while trying to allocate "
+        "16.66G.")
+
+
+def _failing_factories(monkeypatch, failing: dict):
+    """``_candidate_factories`` with the factories of ``failing`` (name →
+    exception) replaced by stubs that raise it, counting their calls."""
+    real = engine_select._candidate_factories
+    calls = {name: 0 for name in failing}
+
+    def patched(*a, **kw):
+        facs = real(*a, **kw)
+        for name, exc in failing.items():
+            def stub(name=name, exc=exc):
+                calls[name] += 1
+                raise exc
+            stub.axes, stub.group_key = facs[name].axes, facs[name].group_key
+            facs[name] = stub
+        return facs
+
+    monkeypatch.setattr(engine_select, "_candidate_factories", patched)
+    return calls
+
+
+@pytest.mark.parametrize("exc", [_oom, MemoryError],
+                         ids=["resource-exhausted", "host-memory"])
+def test_candidate_that_cannot_allocate_is_dropped(small_forest, tmp_path,
+                                                   monkeypatch, exc):
+    from conftest import rand_X
+    from repro.obs.metrics import MetricsRegistry, set_default_registry
+    calls = _failing_factories(monkeypatch, {"native": exc()})
+    cache = str(tmp_path / "engines.json")
+    mine = MetricsRegistry()
+    old = set_default_registry(mine)
+    try:
+        c = engine_select.choose(small_forest, 32, engines=CHEAP,
+                                 cache_path=cache, repeats=1)
+    finally:
+        set_default_registry(old)
+    snap = mine.snapshot()
+    assert c.engine in ("qs", "qs-bitmm") and not c.from_cache
+    assert c.timings["native"] == float("inf")
+    X = rand_X(small_forest, B=32)
+    np.testing.assert_allclose(c.predict(X), small_forest.predict_oracle(X),
+                               rtol=1e-4, atol=1e-5)
+    dropped, = snap["repro_autotune_candidates_dropped_total"]["samples"]
+    assert dropped["labels"] == {"candidate": "native"}
+    assert dropped["value"] == 1.0
+    assert snap["repro_autotune_candidates_benched_total"]["samples"][0][
+        "value"] == 3.0
+    # the drop is cached: a later process neither retries nor re-sweeps
+    engine_select.clear_cache()
+    again = engine_select.choose(small_forest, 32, engines=CHEAP,
+                                 cache_path=cache, repeats=1)
+    assert again.from_cache and again.engine == c.engine
+    assert calls == {"native": 1}
+    with open(cache) as f:
+        entry = json.load(f)[c.key]
+    assert entry["timings"]["native"] == float("inf")
+    assert "native" not in entry["bench_us"]
+
+
+def test_other_candidate_errors_still_raise(small_forest, monkeypatch):
+    _failing_factories(monkeypatch, {"native": ValueError("a real bug")})
+    with pytest.raises(ValueError, match="a real bug"):
+        engine_select.choose(small_forest, 32, engines=CHEAP,
+                             cache_path=None, repeats=1)
+
+
+def test_no_candidate_can_allocate_raises(small_forest, monkeypatch):
+    _failing_factories(monkeypatch, {e: _oom() for e in CHEAP})
+    with pytest.raises(MemoryError, match="no candidate"):
+        engine_select.choose(small_forest, 32, engines=CHEAP,
+                             cache_path=None, repeats=1)

@@ -204,3 +204,51 @@ def test_softmax_gbt_class_embedding(magic_ds):
     X = magic_ds.X_test[:64]
     np.testing.assert_allclose(forest.predict_oracle(X), gb.predict(X),
                                rtol=1e-6, atol=1e-8)
+
+
+# --------------------------------------------------------------------------- #
+# trees wider than 64 leaves: 255 leaves (8 mask words, the last one
+# part-filled), and 33-, 64- and 255-leaf trees in one forest, padded to
+# 255; every engine, the Pallas kernels in the interpreter at their own
+# block sizes (two tree tiles, two row blocks), against the oracle
+# --------------------------------------------------------------------------- #
+WIDE_T, WIDE_D, WIDE_B = 16, 136, 160
+
+
+@pytest.fixture(scope="module", params=["255", "33-64-255"])
+def wide_forest(request):
+    from repro.core.forest import _random_tree, from_trees
+    sizes = {"255": [255], "33-64-255": [33, 64, 255]}[request.param]
+    rng = np.random.default_rng(255)
+    f = from_trees([_random_tree(rng, sizes[t % len(sizes)], WIDE_D, 1,
+                                 False) for t in range(WIDE_T)], WIDE_D, 1)
+    assert (f.n_leaves, f.n_words) == (255, 8)
+    return f
+
+
+@pytest.mark.parametrize("quant", [None, 16], ids=["float32", "int16"])
+@pytest.mark.parametrize("name,backend", COMBOS, ids=COMBO_IDS)
+def test_wide_trees_every_engine_matches_oracle(name, backend, quant,
+                                                wide_forest):
+    # float32 rows: the oracle compares the very values the engines see,
+    # standard normal as the thresholds, so every split sees both sides
+    X = np.random.default_rng(7).standard_normal((WIDE_B, WIDE_D),
+                                                 dtype=np.float32)
+    forest = wide_forest if quant is None else core.quantize_forest(
+        wide_forest, X, core.QuantSpec(bits=quant))
+    got = registry.build(forest, name, backend).predict(X)
+    if quant is None:
+        # float32 sums of the trees' leaves, in another order than the
+        # float64 oracle's: each of at most T additions rounds by at most
+        # 2^-24 of a partial sum, which is below Σ_t max|leaf_t|
+        lv = np.abs(forest.leaf_value).max(axis=(1, 2))
+        tol = WIDE_T * 2.0 ** -24 * lv.sum()
+        np.testing.assert_allclose(got, forest.predict_oracle(X), rtol=0,
+                                   atol=tol)
+    else:
+        # integer leaf sums are exact (below 2^24) and the descale is a
+        # power of two: equal bit for bit
+        Xq = core.quantize_inputs(forest, X)
+        want = forest.predict_oracle(Xq).astype(np.float32) \
+            / np.float32(core.leaf_scale(forest))
+        np.testing.assert_array_equal(got, want)

@@ -229,3 +229,69 @@ def test_phase_records_time_when_the_body_raises():
             with phase("wait"):
                 raise ValueError("boom")
     assert "wait_ms" in acc
+
+
+# --------------------------------------------------------------------------- #
+# the shape a predictor runs at: repro.launch's arguments and the
+# repro_tree_rows_total counter
+# --------------------------------------------------------------------------- #
+WIDE = dict(n_trees=6, n_leaves=40, n_features=7)    # 2 mask words
+
+
+def _wide_forest():
+    return core.random_forest_ir(**WIDE, n_classes=2, seed=6, full=False)
+
+
+@pytest.mark.parametrize("name,backend,tile", [
+    ("bitvector", "jax", {}),
+    # block_t=4 trees a tile, 40 node slots (39 nodes + bias, to 8) and
+    # 40 leaf rows a tree, 2 tree tiles
+    ("bitvector", "pallas", {"node_slots": 160, "leaf_rows": 160,
+                             "tree_tiles": 2}),
+    ("bitmm", "pallas", {"node_slots": 160, "leaf_rows": 160,
+                         "tree_tiles": 2}),
+    ("gemm", "pallas", {"node_slots": 160, "leaf_rows": 160,
+                        "tree_tiles": 2}),
+], ids=["xla-qs", "pallas-qs", "pallas-bitmm", "pallas-gemm"])
+def test_launch_span_carries_the_tile_shape(name, backend, tile, tmp_path):
+    from repro.core import registry
+    kw = {"block_b": 32, "block_t": 4} if backend == "pallas" else {}
+    pred = registry.build(_wide_forest(), name, backend, **kw)
+    X = np.random.default_rng(7).normal(size=(20, 7))
+    pred.predict(X)                              # compile outside the trace
+    events = _traced(tmp_path, lambda: pred.predict(X))
+    (_, _, _, args), = _named(events, "repro.launch")
+    want = dict(engine=registry.get(name, backend).tune_name, trees=6,
+                words=2, **tile)
+    assert args == want
+    assert pred.launch_args == want
+
+
+def test_tree_rows_counter_counts_rows_times_trees():
+    from repro.core import registry
+    from repro.obs.metrics import set_default_registry
+    pred = registry.build(_wide_forest(), "bitvector", "pallas",
+                          block_b=32, block_t=4)
+    X = np.random.default_rng(8).normal(size=(20, 7))
+    mine = MetricsRegistry()
+    old = set_default_registry(mine)
+    try:
+        pred.predict(X)
+        pred.predict(X[:3])
+    finally:
+        set_default_registry(old)
+    (sample,) = mine.snapshot()["repro_tree_rows_total"]["samples"]
+    assert sample["labels"] == {"engine": "pallas-qs"}
+    assert sample["value"] == (20 + 3) * WIDE["n_trees"]     # real rows
+
+
+def test_tree_rows_counter_silent_when_obs_off():
+    from repro.obs.metrics import set_default_registry
+    pred = core.compile_forest(_wide_forest(), engine="bitvector")
+    off = MetricsRegistry(enabled=False)
+    old = set_default_registry(off)
+    try:
+        pred.predict(np.zeros((4, 7)))
+    finally:
+        set_default_registry(old)
+    assert "repro_tree_rows_total" not in off.snapshot()

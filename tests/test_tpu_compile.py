@@ -1,5 +1,6 @@
 """The forest kernels compile for a TPU v5e chip, at the chip smoke's
-widths (1024 trees × 64 leaves, d=784, 10 classes, 2048 rows).
+widths (1024 trees × 64 leaves, d=784, 10 classes, 2048 rows), and the
+three Pallas engines at 255 leaves over 136 features (4096 rows).
 
 No chip is needed: the TPU compiler is installed and compiles for a
 described v5e topology.  Nothing runs, so these tests say nothing about
@@ -51,11 +52,12 @@ def compiled_mode(monkeypatch):
     monkeypatch.setattr(quickscorer_kernel, "interpret_mode", lambda: False)
 
 
-def _compile(one_chip, fn, *arrays):
-    """Lower ``fn`` on shapes placed on the described chip and compile."""
+def _compile(one_chip, fn, *arrays, rows=B, d=D):
+    """Lower ``fn`` on shapes placed on the described chip and compile;
+    ``fn`` takes a ``(rows, d)`` float32 block first."""
     import jax
     import jax.numpy as jnp
-    x = jax.ShapeDtypeStruct((B, D), jnp.float32, sharding=one_chip)
+    x = jax.ShapeDtypeStruct((rows, d), jnp.float32, sharding=one_chip)
     shapes = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
               for a in arrays]
     return jax.jit(fn).lower(x, *shapes).compile()
@@ -168,4 +170,40 @@ def test_folded_qs_program_compiles(one_chip, forest, compiled_mode):
     assert pred.folds_inputs(np.zeros((1, D), np.float32))
     x = jax.ShapeDtypeStruct((B, D), jnp.float32, sharding=one_chip)
     c = pred._program_for(True).lower(x).compile()
+    assert "tpu_custom_call" in c.as_text()
+
+
+# trees of 255 leaves (8 mask words, 2048 node slots and leaf rows per
+# tile) over 136 features, 4096 rows: the LightGBM ranker's widths, two
+# tree tiles (a kernel's body is one tile's, whatever the tree count)
+WIDE_T, WIDE_L, WIDE_D, WIDE_B = 16, 255, 136, 4096
+
+
+@pytest.fixture(scope="module")
+def wide_forest():
+    from repro import core
+    return core.random_forest_ir(WIDE_T, WIDE_L, WIDE_D, seed=3, full=False)
+
+
+@pytest.mark.parametrize("kernel", ["qs", "bitmm", "gemm"])
+def test_kernels_compile_at_255_leaves(one_chip, wide_forest, compiled_mode,
+                                       kernel):
+    from repro.kernels import gemm_forest_kernel, ops, quickscorer_kernel
+    f = wide_forest
+    assert f.n_words == 8
+    if kernel == "qs":
+        arrays = ops._qs_arrays(f, BLOCK_T)
+        fn = lambda x, *a: quickscorer_kernel.qs_forward(   # noqa: E731
+            x, *a, block_b=BLOCK_B, block_t=BLOCK_T)
+    elif kernel == "bitmm":
+        *arrays, bits, npack = ops._bitmm_arrays(f, BLOCK_T)
+        fn = lambda x, *a: quickscorer_kernel.qs_bitmm_forward(  # noqa: E731
+            x, *a, bits=bits, npack=npack, n_leaves=f.n_leaves,
+            block_b=BLOCK_B, block_t=BLOCK_T)
+    else:
+        arrays = ops._gemm_arrays(f, BLOCK_T)
+        fn = lambda x, *a: gemm_forest_kernel.gemm_forward(  # noqa: E731
+            x, *a, block_b=BLOCK_B)
+    assert arrays[0].shape[-1] == BLOCK_T * 256          # node slots a tile
+    c = _compile(one_chip, fn, *arrays, rows=WIDE_B, d=WIDE_D)
     assert "tpu_custom_call" in c.as_text()
